@@ -1,8 +1,11 @@
-"""Three-term roofline analysis from dry-run artifacts (TPU v5e).
+"""Three-term roofline analysis from dry-run artifacts.
 
     compute term    = HLO_FLOPs_per_chip / peak_FLOP/s
     memory term     = HLO_bytes_per_chip / HBM_bw
     collective term = collective_bytes_per_chip / link_bw
+
+The peaks are those of the record's ``device_kind`` (``PEAKS``); a device
+kind missing from the table is an error, never a default.
 
 ``compiled.cost_analysis()`` reports the PER-DEVICE program (the SPMD
 partition), so terms divide by per-chip peaks directly.  Collective bytes
@@ -24,10 +27,31 @@ from typing import Any, Dict, List, Optional
 
 from repro.configs import get_config, get_shape
 
-# TPU v5e hardware constants (per chip)
-PEAK_FLOPS = 197e12          # bf16 FLOP/s
-HBM_BW = 819e9               # bytes/s
-ICI_BW = 50e9                # bytes/s per link (~usable per-chip collective BW)
+
+@dataclass(frozen=True)
+class Peaks:
+    """Published per-chip peaks of one accelerator."""
+    flops: float             # bf16 FLOP/s
+    hbm_bw: float            # HBM bytes/s
+    hbm_bytes: int           # HBM capacity
+    ici_bw: float            # bytes/s of one chip-to-chip link
+
+
+# Keyed by jax's ``device.device_kind``.  Source: Google Cloud
+# documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB of HBM at 819 GB/s,
+# 1,600 Gbit/s of interconnect per chip (50 GB/s on each of 4 links).
+PEAKS: Dict[str, Peaks] = {
+    "TPU v5 lite": Peaks(flops=197e12, hbm_bw=819e9, hbm_bytes=16 * 2 ** 30,
+                         ici_bw=50e9),
+}
+
+
+def peaks_for(device_kind: str) -> Peaks:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 
 @dataclass
@@ -80,9 +104,10 @@ def analyze(record: Dict[str, Any]) -> Optional[RooflineRow]:
     bytes_chip = float(record["cost"]["bytes_accessed"] or 0.0)
     coll_chip = float(record["collectives"]["total_bytes"] or 0.0)
 
-    compute_s = flops_chip / PEAK_FLOPS
-    memory_s = bytes_chip / HBM_BW
-    collective_s = coll_chip / ICI_BW
+    peaks = peaks_for(record["device_kind"])
+    compute_s = flops_chip / peaks.flops
+    memory_s = bytes_chip / peaks.hbm_bw
+    collective_s = coll_chip / peaks.ici_bw
     terms = {"compute": compute_s, "memory": memory_s,
              "collective": collective_s}
     dominant = max(terms, key=terms.get)
@@ -97,7 +122,7 @@ def analyze(record: Dict[str, Any]) -> Optional[RooflineRow]:
         per_chip = (mem["argument_bytes"] + (mem.get("temp_bytes") or 0)
                     + (mem.get("output_bytes") or 0)
                     - (mem.get("alias_bytes") or 0))
-        fits = per_chip <= 16 * 1024 ** 3
+        fits = per_chip <= peaks.hbm_bytes
 
     return RooflineRow(
         arch=record["arch"], shape=record["shape"], mesh=record["mesh"],

@@ -67,9 +67,12 @@ def pad_batch(batch: Dict[str, np.ndarray], bucket: int,
 class FlexibleBatcher:
     """Wraps a batch-polymorphic function with bucketed jit dispatch.
 
-    fn(batch_dict) -> pytree with leading batch axis.  Calls with ANY
-    batch size n <= max bucket; output is sliced back to n rows.
-    Tracks per-bucket compilation, proving the jit cache stays bounded.
+    fn(batch_dict, *args) -> pytree with leading batch axis.  Calls with
+    ANY batch size n <= max bucket; output is sliced back to n rows.
+    ``args`` (model params) pass through unpadded as jit ARGUMENTS: a
+    closure over them would bake every weight into the program as a
+    constant.  Tracks per-bucket compilation, proving the jit cache stays
+    bounded.
     """
 
     def __init__(self, fn: Callable, buckets: BucketSpec,
@@ -84,13 +87,13 @@ class FlexibleBatcher:
         probe = getattr(self._fn, "_cache_size", None)
         return probe() if callable(probe) else None
 
-    def __call__(self, batch: Dict[str, Any]):
+    def __call__(self, batch: Dict[str, Any], *args):
         n = next(iter(batch.values())).shape[0]
         bucket = self.buckets.bucket_for(n)
         padded, _mask = pad_batch(batch, bucket)
         self.calls += 1
         before = self._cache_size()
-        out = self._fn(padded)
+        out = self._fn(padded, *args)
         after = self._cache_size()
         if before is None or after is None:
             # no cache introspection on this jax — fall back to first-call
@@ -105,7 +108,7 @@ class FlexibleBatcher:
     def num_compilations(self) -> int:
         return sum(self.compiles.values())
 
-    def warm(self, example_batch: Dict[str, Any],
+    def warm(self, example_batch: Dict[str, Any], *args,
              buckets: Optional[Sequence[int]] = None) -> float:
         """Pre-compile bucket specializations off the hot path.
 
@@ -121,7 +124,7 @@ class FlexibleBatcher:
             # exactly b rows -> bucket_for(b) == b: one compile per bucket
             batch = {k: (v[:b] if n >= b else pad_to(v, b))
                      for k, v in example.items()}
-            jax.block_until_ready(self(batch))
+            jax.block_until_ready(self(batch, *args))
         return time.perf_counter() - t0
 
 

@@ -71,15 +71,13 @@ class _EnsembleState:
             raise ValueError(f"duplicate member names: {names}")
         self._param_list = [m.params for m in self.members]
 
-        def _forward_all(param_list, batch):
+        def _forward_all(batch, param_list):
             # ONE jitted computation spanning every member
             return {m.name: m.apply(p, batch)
                     for m, p in zip(self.members, param_list)}
 
-        self._forward = jax.jit(_forward_all)
-        self.batcher = FlexibleBatcher(
-            lambda batch: self._forward(self._param_list, batch),
-            BucketSpec.pow2(max_batch))
+        self.batcher = FlexibleBatcher(_forward_all,
+                                       BucketSpec.pow2(max_batch))
         self._inflight = 0
         self._cv = threading.Condition()
 
@@ -87,14 +85,14 @@ class _EnsembleState:
         with self._cv:
             self._inflight += 1
         try:
-            return self.batcher(batch)
+            return self.batcher(batch, self._param_list)
         finally:
             with self._cv:
                 self._inflight -= 1
                 self._cv.notify_all()
 
     def warm(self, example_batch: Dict[str, Any]) -> float:
-        return self.batcher.warm(example_batch)
+        return self.batcher.warm(example_batch, self._param_list)
 
     def drain(self, timeout: float) -> bool:
         """Block until no forward is executing on this state (or timeout)."""

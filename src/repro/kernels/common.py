@@ -5,7 +5,6 @@ from __future__ import annotations
 import functools
 
 import jax
-from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -14,15 +13,6 @@ NEG_INF = -1e30
 def default_interpret() -> bool:
     """Interpret Pallas kernels unless running on a real TPU."""
     return jax.default_backend() != "tpu"
-
-
-def tpu_compiler_params(**kw):
-    """``pltpu.TPUCompilerParams`` was renamed ``CompilerParams`` across
-    jax releases; resolve whichever this install provides."""
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kw)
 
 
 def cdiv(a: int, b: int) -> int:

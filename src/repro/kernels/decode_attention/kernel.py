@@ -7,8 +7,9 @@ through VMEM exactly once.
 
 Grid: (batch, kv_heads, num_kv_blocks), kv innermost/sequential with the
 online-softmax running stats in VMEM scratch.  Per-row valid ``lengths``
-live in SMEM (scalar-like), giving the ragged masking continuous batching
-needs; sliding-window serving masks kv below (length - window).
+ride in as a scalar-prefetch operand (SMEM), giving the ragged masking
+continuous batching needs; sliding-window serving masks kv below
+(length - window).
 
 VMEM working set with kv_blk=512, hd=128, G<=8:
 2 * 512*128 (k,v tile) * 4B + G*128 acc ≈ 0.5 MiB.
@@ -24,12 +25,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import (NEG_INF, default_interpret,
-                                  tpu_compiler_params)
+from repro.kernels.common import NEG_INF, default_interpret
 
 
 def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *,
             window: Optional[int], kv_blk: int, nk: int):
+    b = pl.program_id(0)
     j = pl.program_id(2)
 
     @pl.when(j == 0)
@@ -38,7 +39,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *,
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
 
-    length = len_ref[0]                                   # this row's #valid
+    length = len_ref[b]                                   # this row's #valid
     q = q_ref[0, 0].astype(jnp.float32)                   # (G, hd)
     k = k_ref[0, 0].astype(jnp.float32)                   # (kv_blk, hd)
     v = v_ref[0, 0].astype(jnp.float32)
@@ -84,24 +85,31 @@ def decode_attention_bkgd(q, k, v, lengths, *, window: Optional[int] = None,
     assert Smax % kv_blk == 0
     nk = Smax // kv_blk
     kern = functools.partial(_kernel, window=window, kv_blk=kv_blk, nk=nk)
-    return pl.pallas_call(
-        kern,
+    # lengths is a scalar-prefetch operand, not a (1,) SMEM block: the TPU
+    # lowering accepts a rank-1 block only when it spans the whole array
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B, K, nk),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, j: (b,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, G, hd), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, kv_blk, hd), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, kv_blk, hd), lambda b, h, j: (b, h, j, 0)),
+            pl.BlockSpec((1, 1, G, hd), lambda b, h, j, len_ref: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, kv_blk, hd),
+                         lambda b, h, j, len_ref: (b, h, j, 0)),
+            pl.BlockSpec((1, 1, kv_blk, hd),
+                         lambda b, h, j, len_ref: (b, h, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, hd), lambda b, h, j: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_specs=pl.BlockSpec((1, 1, G, hd),
+                               lambda b, h, j, len_ref: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, hd), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+    )
+    return pl.pallas_call(
+        kern,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="decode_attention",
@@ -203,7 +211,7 @@ def decode_attention_paged_bkgd(q, k_pages, v_pages, page_table, lengths, *,
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="decode_attention_paged",

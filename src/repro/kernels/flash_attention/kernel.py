@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import NEG_INF, cdiv, tpu_compiler_params
+from repro.kernels.common import NEG_INF
 
 
 def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *,
@@ -32,7 +32,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *,
     b = pl.program_id(0)          # batch row
     i = pl.program_id(2)          # q block
     j = pl.program_id(3)          # kv block
-    seq_len = len_ref[0]          # this row's valid kv length (ragged)
+    seq_len = len_ref[b]          # this row's valid kv length (ragged)
 
     @pl.when(j == 0)
     def _init():
@@ -94,27 +94,32 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True,
 
     if lengths is None:
         lengths = jnp.full((B,), seq_len, jnp.int32)
-    return pl.pallas_call(
-        kern,
+    # lengths is a scalar-prefetch operand, not a (1,) SMEM block: the TPU
+    # lowering accepts a rank-1 block only when it spans the whole array
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B, H, nq, nk),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, i, j: (b,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, q_blk, hd), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, q_blk, hd),
+                         lambda b, h, i, j, len_ref: (b, h, i, 0)),
             pl.BlockSpec((1, 1, kv_blk, hd),
-                         lambda b, h, i, j: (b, h // G, j, 0)),
+                         lambda b, h, i, j, len_ref: (b, h // G, j, 0)),
             pl.BlockSpec((1, 1, kv_blk, hd),
-                         lambda b, h, i, j: (b, h // G, j, 0)),
+                         lambda b, h, i, j, len_ref: (b, h // G, j, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, q_blk, hd),
-                               lambda b, h, i, j: (b, h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+                               lambda b, h, i, j, len_ref: (b, h, i, 0)),
         scratch_shapes=[
             pltpu.VMEM((q_blk, 1), jnp.float32),
             pltpu.VMEM((q_blk, 1), jnp.float32),
             pltpu.VMEM((q_blk, hd), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+    )
+    return pl.pallas_call(
+        kern,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

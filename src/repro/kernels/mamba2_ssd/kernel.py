@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import tpu_compiler_params
 
 
 def _kernel(x_ref, dt_ref, da_ref, b_ref, c_ref, h0_ref, y_ref, hT_ref, h_s,
@@ -98,7 +97,7 @@ def ssd_bhtp(x, dt, dA, Bm, Cm, h0, *, chunk: int = 128,
         out_shape=[jax.ShapeDtypeStruct((B, H, T, P), x.dtype),
                    jax.ShapeDtypeStruct((B, H, P, N), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="mamba2_ssd",

@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import tpu_compiler_params
 
 
 def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, y_ref, sT_ref, s_s,
@@ -92,7 +91,7 @@ def wkv6_bhtn(r, k, v, logw, u, s0, *, chunk: int = 32,
         out_shape=[jax.ShapeDtypeStruct((B, H, T, N), r.dtype),
                    jax.ShapeDtypeStruct((B, H, N, N), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((N, N), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="rwkv6_wkv",

@@ -37,7 +37,7 @@ from repro.configs import ASSIGNED_ARCHS, get_config, get_shape, SHAPES
 from repro.configs.base import ModelConfig
 from repro.configs.shapes import InputShape
 from repro.launch import shardings as shd
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import PRODUCTION_DEVICE_KIND, make_production_mesh
 from repro.models.build import build_model
 from repro.sharding import use_mesh
 from repro.training import optimizer
@@ -255,6 +255,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
         "opt_flags": opt_flags.all_flags(),
         "grad_accum": grad_accum,
         "n_devices": int(mesh.devices.size),
+        "device_kind": PRODUCTION_DEVICE_KIND,
         "lower_s": round(t_lower, 2), "compile_s": round(t_compile, 2),
         "memory": {
             "argument_bytes": getattr(mem, "argument_size_in_bytes", None),

@@ -8,6 +8,10 @@ XLA_FLAGS=--xla_force_host_platform_device_count BEFORE first jax init.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+# jax's device_kind of the chip the production meshes are made of (v5e)
+PRODUCTION_DEVICE_KIND = "TPU v5 lite"
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -15,7 +19,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod:  (pod=2, data=16, model=16) = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
@@ -23,7 +27,13 @@ def make_local_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     data = min(data, n)
     model = max(1, min(model, n // data))
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
+
+
+def _auto_mesh(shape, axes):
+    # Auto axes: sharding.shard() places values with with_sharding_constraint,
+    # which jax.make_mesh's default Explicit axes reject.
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def mesh_num_chips(mesh) -> int:

@@ -1,7 +1,11 @@
 """Serving launcher: deploy one or more archs behind a FlexServe endpoint.
 
-CPU/container mode serves REDUCED variants (the paper's kind of
-deployment, runnable here); --full targets the production mesh on TPU.
+By default every arch is served as its REDUCED smoke variant (2 layers,
+float32), which runs anywhere.  ``--full`` loads the published widths
+(bf16, random weights from the seed) on the one local device; no mesh is
+built and nothing is sharded.  Full-width ``h2o-danube-1.8b`` fits one
+16 GB TPU v5e chip; full-width ``yi-9b`` (~8.8B parameters, ~17.6 GB of
+bf16 weights) does not.
 
   PYTHONPATH=src python -m repro.launch.serve \
       --ensemble yi-9b yi-9b h2o-danube-1.8b --port 8000
@@ -19,7 +23,9 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import os
 import sys
+from pathlib import Path
 
 import jax
 
@@ -30,6 +36,21 @@ from repro.models.build import build_model
 from repro.core.faults import FaultInjector
 from repro.serving import (FlexServeApp, FlexServeServer, ModelManager,
                            ModelStore)
+
+CHECKOUT = Path(__file__).resolve().parents[3]
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compile cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, where set, is read by JAX itself and
+    left alone.  Otherwise the cache lives at the fixed ``<checkout>/
+    .jax_cache``, so each run of a checkout finds what the last one
+    compiled.  Call it from an entry point, before the first compile."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          str(CHECKOUT / ".jax_cache"))
+    return jax.config.jax_compilation_cache_dir
 
 
 def build_app(arch_names, *, num_classes: int = 16, max_len: int = 256,
@@ -182,6 +203,24 @@ def build_store_app(arch_names, store_dir: str, *, num_classes: int = 16,
     return app
 
 
+def boot(arch_names, *, model_store=None, **kw):
+    """Build the app the way ``main`` serves it and pre-compile its decode
+    data path (fused decode step, batched-prefill buckets, slot scatter),
+    so the first live streams never pay compile latency.  Store-backed
+    boots skip the warm step: the manager's load_engine already warmed
+    before flipping the alias.  Returns ``(app, warm seconds or None)``."""
+    if model_store:
+        app = build_store_app(arch_names, model_store, **kw)
+    else:
+        app = build_app(arch_names, **kw)
+    warm_s = None
+    if (app.generation is not None and app.generation.ready
+            and app.manager is None):
+        warm_s = app.generation.entry_for().service.warm()
+        print(f"[serve] decode path warm in {warm_s:.1f}s")
+    return app, warm_s
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--ensemble", nargs="+", default=["yi-9b"],
@@ -281,18 +320,8 @@ def main(argv=None) -> int:
               client_weights=client_weights, draft_model=args.draft_model,
               draft_layers=args.draft_layers, spec_window=args.spec_window,
               replicas=args.replicas, fault_config=args.fault_config)
-    if args.model_store:
-        app = build_store_app(args.ensemble, args.model_store, **kw)
-    else:
-        app = build_app(args.ensemble, **kw)
-    if (app.generation is not None and app.generation.ready
-            and app.manager is None):
-        # pre-compile the decode data path (fused decode step, batched-
-        # prefill buckets, slot scatter) so the first live streams never
-        # pay compile latency.  Store-backed boots skip this: the
-        # manager's load_engine already warmed before flipping the alias.
-        warm_s = app.generation.entry_for().service.warm()
-        print(f"[serve] decode path warm in {warm_s:.1f}s")
+    use_compile_cache()
+    app, _ = boot(args.ensemble, model_store=args.model_store, **kw)
     if args.replicas > 1:
         print(f"[serve] replica pool: {args.replicas} decode replicas "
               f"(health-checked; GET /v1/replicas)")

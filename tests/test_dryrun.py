@@ -39,6 +39,7 @@ def test_dryrun_combo_compiles(arch, shape, multi_pod, tmp_path):
         rec = json.load(f)
     assert rec["status"] == "ok"
     assert rec["n_devices"] == (512 if multi_pod else 256)
+    assert rec["device_kind"] == "TPU v5 lite"
     assert rec["cost"]["flops"] > 0
     assert rec["memory"]["argument_bytes"] > 0
 
@@ -49,6 +50,7 @@ def test_roofline_analysis_on_record(tmp_path):
     rec = {
         "status": "ok", "arch": "yi-9b", "shape": "decode_32k",
         "mesh": "pod16x16", "step": "serve_step", "n_devices": 256,
+        "device_kind": "TPU v5 lite",
         "cost": {"flops": 1e9, "bytes_accessed": 1e9},
         "collectives": {"total_bytes": 1e6},
         "memory": {"argument_bytes": 2 * 2 ** 30, "temp_bytes": 2 ** 30,
@@ -59,3 +61,10 @@ def test_roofline_analysis_on_record(tmp_path):
     assert row.fits_hbm is True
     assert 0 < row.useful_ratio < 10
     assert "memory" in roofline.what_would_help(row)
+
+
+def test_roofline_refuses_unknown_device_kind():
+    from repro.analysis import roofline
+    assert roofline.peaks_for("TPU v5 lite").hbm_bw == 819e9
+    with pytest.raises(KeyError, match="no published peaks"):
+        roofline.peaks_for("cpu")

@@ -1,6 +1,8 @@
 """Ensemble behaviour (paper claims C1 + C2): one forward call over N
 models, shared memory accounting, paper-schema responses."""
 
+import warnings
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -80,3 +82,20 @@ def test_memory_ledger_counts_all_members(ensemble):
     assert ledger.fits()
     rep = ledger.report()
     assert "FITS" in rep
+
+
+def test_forward_takes_weights_as_arguments():
+    """Member params reach the batched jit as arguments.  Captured as
+    constants they are copied into every compiled bucket program, which
+    at published widths exhausts the host while lowering."""
+    ens = Ensemble(_members(n=2), max_batch=4)
+    was = jax.config.jax_captured_constants_warn_bytes
+    jax.config.update("jax_captured_constants_warn_bytes", 1)
+    try:
+        with warnings.catch_warnings():
+            warnings.filterwarnings("error", message=".*constants were "
+                                    "captured")
+            out = ens.forward({"tokens": np.ones((3, 8), np.int32)})
+    finally:
+        jax.config.update("jax_captured_constants_warn_bytes", was)
+    assert next(iter(out.values())).shape[0] == 3
