@@ -28,13 +28,8 @@ import numpy as np
 from repro.core.batching import BucketSpec, pad_sequences
 from repro.core.sampling import (SamplingParams, base_key, sample_tokens,
                                  samplers_for)
+from repro.core.telemetry import span
 from repro.models.build import Model
-
-# Named profiler regions: an on-demand jax.profiler capture (POST
-# /v1/debug/profile) shows the serving data path as labelled rows instead
-# of anonymous XLA launches.  TraceAnnotation is a TraceMe — nanoseconds
-# when no capture is active — so it stays on permanently.
-_annotate = jax.profiler.TraceAnnotation
 
 
 @dataclass
@@ -58,7 +53,6 @@ class InferenceEngine:
         # forward-call accounting (batched prefill shows up as fewer
         # prefill calls than admitted requests)
         self.prefill_calls = 0
-        self.decode_calls = 0
 
         kw = {}
         if window is not None:
@@ -91,12 +85,11 @@ class InferenceEngine:
 
     def prefill(self, batch: Dict[str, Any], state):
         self.prefill_calls += 1
-        with _annotate("flexserve.prefill"):
+        with span("prefill"):
             return self._prefill(self.params, batch, state)
 
     def decode(self, token, state):
-        self.decode_calls += 1
-        with _annotate("flexserve.decode"):
+        with span("decode"):
             return self._decode(self.params, token, state)
 
     def decode_sample(self, token, state, samp: Dict[str, Any], ctr):
@@ -106,8 +99,7 @@ class InferenceEngine:
         int32 device array, new_state, ctr+1)`` — the ids are the ONLY
         thing a caller needs to pull to host; ids and counters feed the
         next tick without leaving the device."""
-        self.decode_calls += 1
-        with _annotate("flexserve.decode_sample"):
+        with span("decode_sample"):
             return self._decode_sample(self.params, token, state,
                                        samp["temperature"], samp["top_k"],
                                        samp["top_p"], samp["key"], ctr)
@@ -115,7 +107,7 @@ class InferenceEngine:
     def sample(self, logits, samp: Dict[str, Any], ctr):
         """On-device sampling of standalone logits (the prefill first-token
         path); same per-row contract as ``decode_sample``."""
-        with _annotate("flexserve.sample"):
+        with span("sample"):
             return self._sample(logits, samp["temperature"],
                                 samp["top_k"], samp["top_p"],
                                 samp["key"], ctr)
@@ -155,7 +147,7 @@ class InferenceEngine:
                                               batch_axes)
 
             self._insert_rows = jax.jit(insert)
-        with _annotate("flexserve.insert_rows"):
+        with span("insert_rows"):
             return self._insert_rows(pool_state, group_state, src_rows,
                                      write_mask)
 
@@ -407,7 +399,7 @@ class PagedInferenceEngine(InferenceEngine):
         Returns ``(first-token logits, new state)`` — the pool is updated
         in place (donated); table/length device arrays pass through."""
         self.prefill_calls += 1
-        with _annotate("flexserve.paged_prefill"):
+        with span("paged_prefill"):
             return self._paged_prefill(self.params, tokens, lengths, state,
                                        ctx_table, ctx_lens, dest_table)
 
@@ -507,7 +499,6 @@ class SpeculativeEngine(InferenceEngine):
         self.batch_buckets = target.batch_buckets
         self.seq_buckets = target.seq_buckets
         self.prefill_calls = 0
-        self.decode_calls = 0
         self._sample = target._sample
         self._state_axes = None
         self._insert_rows = None
@@ -590,7 +581,6 @@ class SpeculativeEngine(InferenceEngine):
         return logits, self._combine(new_t, new_d)
 
     def decode(self, token, state):
-        self.decode_calls += 1
         logits, new_t = self.target.decode(token, self._view(state,
                                                              "target"))
         return logits, self._combine(new_t,
@@ -606,8 +596,7 @@ class SpeculativeEngine(InferenceEngine):
         """Non-speculative tick on the pair: the TARGET's own fused
         decode-sample program over a view of the combined state — level-1
         backoff compiles nothing new."""
-        self.decode_calls += 1
-        with _annotate("flexserve.decode_sample"):
+        with span("decode_sample"):
             toks, new_t, ctr2 = self.target._decode_sample(
                 self.target.params, token, self._view(state, "target"),
                 samp["temperature"], samp["top_k"], samp["top_p"],
@@ -625,11 +614,10 @@ class SpeculativeEngine(InferenceEngine):
         next_token (B), new_state, ctr + counts)`` — row b emitted
         ``draws[b, :counts[b]]``; rows with ``spec_on[b]`` False advance
         exactly one (sequential-identical) token."""
-        self.decode_calls += 1
         fn = self._spec_steps.get(w)
         if fn is None:
             fn = self._spec_steps[w] = self._build_spec_step(w)
-        with _annotate("flexserve.speculative_step"):
+        with span("speculative_step"):
             return fn(self.target.params, self.draft.params, state, token,
                       samp["temperature"], samp["top_k"], samp["top_p"],
                       samp["key"], ctr, spec_on)

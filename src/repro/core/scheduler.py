@@ -67,7 +67,8 @@ import numpy as np
 from repro.core.engine import GenerationResult, InferenceEngine
 from repro.core.kv_pager import KVPager, PagerOOM, PrefixMatch
 from repro.core.sampling import (SamplingParams, TokenSampler, base_key)
-from repro.core.telemetry import (BYTES_BUCKETS, Histogram, Reservoir, pctl)
+from repro.core.telemetry import (BYTES_BUCKETS, Histogram, PhaseClock,
+                                  Reservoir, pctl)
 
 # sink(request, token, done): token is None only for a terminal
 # notification that produced no token (cancellation, driver error)
@@ -142,7 +143,28 @@ class Request:
 # pctl is imported from repro.core.telemetry and re-exported here for the
 # benches/coalescer that historically imported it from this module
 
+
+def zero_phase_stats() -> Dict[str, Any]:
+    """The decode section's phase keys before any tick, so the /metrics
+    schema is the same with or without an engine."""
+    return {**PhaseClock("sched", SCHED_PHASES).stats(),
+            "dispatch_ms": 0.0, "fetch_ms": 0.0}
+
+
+def _per(phases: Dict[str, Dict[str, float]], name: str) -> float:
+    """Mean host milliseconds of one phase per entry (0 before any)."""
+    n = phases["phase_count"][name]
+    return phases["phase_ms_total"][name] / n if n else 0.0
+
 _WINDOW = 4096                  # bounded stat windows (trimmed to half)
+
+# phases of the decode driver's thread (``flexserve.sched.<phase>`` spans):
+# ``wait`` for work; per tick ``reap``, ``admit`` (batch build, uploads,
+# prefill, first-token sample, slot scatter), ``dispatch`` (the decode
+# call), ``fetch`` (the token ids to host: waits for the device), ``emit``
+# (token loop, stream notification, accounting, slot frees); ``loop`` is
+# the driver's own work between ticks
+SCHED_PHASES = ("wait", "reap", "admit", "dispatch", "fetch", "emit", "loop")
 
 # keys a pager stats() dict carries, zeroed for dense engines so the
 # /metrics "pager" section has a stable schema either way
@@ -280,6 +302,7 @@ class ContinuousBatchingScheduler:
         # the sampling path)
         self.decode_ticks = 0
         self.decode_transfer_bytes = 0       # lifetime, decode ticks only
+        self.phases = PhaseClock("sched", SCHED_PHASES)
         # cumulative per-slot SHARES: each decode tick adds that tick's
         # evenly-split cost exactly once (1 tick, device_ms/active,
         # host_ms/active, transfer/active).  A request marks these at slot
@@ -433,169 +456,180 @@ class ContinuousBatchingScheduler:
     def step(self) -> List[Request]:
         """Reap cancellations/pauses/expiries + admit-from-queue + one
         decode step.  Returns every request that finished during this
-        tick."""
-        if self.faults is not None:
-            # "decode_tick": stall/slow sleeps inside the driver loop (a
-            # wedged decode loop the health monitor must notice); "raise"
-            # poisons the tick like any driver error
-            self.faults.fire("decode_tick", tick=self.steps)
-        t_tick = time.perf_counter()
-        finished = self._reap()
-        prefill_s = self._admit(finished)
-        self.prefill_s_total += prefill_s
-        if self.paged:
-            self._ensure_decode_pages()
+        tick.  Its phases (``reap``, ``admit``, ``dispatch``, ``fetch``,
+        ``emit``) tile the tick on ``self.phases``."""
+        phase = self.phases.phase
+        tick = self.steps
+        with phase("reap", tick=tick):
+            if self.faults is not None:
+                # "decode_tick": stall/slow sleeps inside the driver loop
+                # (a wedged decode loop the health monitor must notice);
+                # "raise" poisons the tick like any driver error
+                self.faults.fire("decode_tick", tick=self.steps)
+            t_tick = time.perf_counter()
+            finished = self._reap()
+        with phase("admit", tick=tick):
+            prefill_s = self._admit(finished)
+            self.prefill_s_total += prefill_s
+            if self.paged:
+                self._ensure_decode_pages()
         if self.active == 0:
             return finished
-        if self.faults is not None:
-            # "engine_step": a poisoned device step — raises after
-            # admission so the in-flight batch takes the failure
-            self.faults.fire("engine_step", tick=self.steps)
-        if self.paged:
-            self._sync_paged_state()
-        spec_w = self._spec_window_for_tick()
-        t_dev = time.perf_counter()
-        draws = counts = None
-        if self.device_sampling:
-            # fused decode + on-device sampling: ONLY the (num_slots,)
-            # token-id vector crosses to host this tick.  Sampling params,
-            # token ids, and rng counters are uploaded only when a slot
-            # changed hands; steady-state ticks upload nothing.
-            if self._samp_dev is None:
-                self._samp_dev = {
-                    "temperature": jnp.asarray(self._temps),
-                    "top_k": jnp.asarray(self._top_ks),
-                    "top_p": jnp.asarray(self._top_ps),
-                    "key": jnp.asarray(self._keys)}
-                self._tok_dev = jnp.asarray(self._last_token)
-                self._ctr_dev = jnp.asarray(self._ctr)
-                self._spec_dev = jnp.asarray(self._spec_on)
-            if spec_w is not None:
-                # draft-propose + verify + accept in one device program:
-                # the host sees token ids and per-slot accepted counts —
-                # (num_slots, w) + (num_slots,) int32 — never logits
-                (draws_dev, counts_dev, tok_dev, self.state,
-                 ctr_dev) = self.engine.speculative_step(
-                    spec_w, self._tok_dev, self.state, self._samp_dev,
-                    self._ctr_dev, self._spec_dev)
+        with phase("dispatch", tick=tick):
+            if self.faults is not None:
+                # "engine_step": a poisoned device step — raises after
+                # admission so the in-flight batch takes the failure
+                self.faults.fire("engine_step", tick=self.steps)
+            if self.paged:
+                self._sync_paged_state()
+            spec_w = self._spec_window_for_tick()
+            t_dev = time.perf_counter()
+            if self.device_sampling:
+                # fused decode + on-device sampling: ONLY the (num_slots,)
+                # token-id vector crosses to host this tick.  Sampling
+                # params, token ids, and rng counters are uploaded only
+                # when a slot changed hands; steady-state ticks upload
+                # nothing.
+                if self._samp_dev is None:
+                    self._samp_dev = {
+                        "temperature": jnp.asarray(self._temps),
+                        "top_k": jnp.asarray(self._top_ks),
+                        "top_p": jnp.asarray(self._top_ps),
+                        "key": jnp.asarray(self._keys)}
+                    self._tok_dev = jnp.asarray(self._last_token)
+                    self._ctr_dev = jnp.asarray(self._ctr)
+                    self._spec_dev = jnp.asarray(self._spec_on)
+                if spec_w is not None:
+                    # draft-propose + verify + accept in one device
+                    # program: the host sees token ids and per-slot
+                    # accepted counts — (num_slots, w) + (num_slots,)
+                    # int32 — never logits
+                    (draws_dev, counts_dev, tok_dev, self.state,
+                     ctr_dev) = self.engine.speculative_step(
+                        spec_w, self._tok_dev, self.state, self._samp_dev,
+                        self._ctr_dev, self._spec_dev)
+                else:
+                    tok_dev, self.state, ctr_dev = self.engine.decode_sample(
+                        self._tok_dev, self.state, self._samp_dev,
+                        self._ctr_dev)
+            else:
+                token = jnp.asarray(self._last_token)
+                # reference host path: full logits cross when any slot
+                # samples
+                logits, self.state = self.engine.decode(token, self.state)
+                greedy_only = all(req is None or req.sampler.params.greedy
+                                  for req in self.slots)
+                if greedy_only:
+                    greedy_dev = jnp.argmax(logits, axis=-1)
+        with phase("fetch", tick=tick):
+            draws = counts = tokens = host = greedy = None
+            if self.device_sampling and spec_w is not None:
                 draws = np.asarray(draws_dev)        # blocks: device sync
                 counts = np.asarray(counts_dev)
                 transfer = draws.nbytes + counts.nbytes
-                tokens = host = greedy = None
-            else:
-                tok_dev, self.state, ctr_dev = self.engine.decode_sample(
-                    self._tok_dev, self.state, self._samp_dev,
-                    self._ctr_dev)
+            elif self.device_sampling:
                 tokens = np.asarray(tok_dev)         # blocks: device sync
                 transfer = tokens.nbytes
-                host = greedy = None
-        else:
-            token = jnp.asarray(self._last_token)
-            # reference host path: full logits cross when any slot samples
-            logits, self.state = self.engine.decode(token, self.state)
-            if all(req is None or req.sampler.params.greedy
-                   for req in self.slots):
-                host = None
-                greedy = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+            elif greedy_only:
+                greedy = np.asarray(greedy_dev, np.int32)
                 transfer = greedy.nbytes
             else:
                 host = np.asarray(logits)            # (num_slots, V)
-                greedy = None
                 transfer = host.nbytes
-            tokens = None
-        device_s = time.perf_counter() - t_dev
-        self.steps += 1
-        self.decode_ticks += 1
-        self.decode_transfer_bytes += transfer
-        if self.speculative:
-            self._spec_account(spec_w, counts, device_s)
-        self._push(self.tick_transfer_window, transfer)
-        # per-request decode accounting rides as counters, not spans: a
-        # request may decode for thousands of ticks and a span per tick
-        # would defeat the bounded-trace design.  The per-tick device/
-        # transfer cost splits evenly across the slots that shared it —
-        # accumulated ONCE per tick into the cumulative share counters;
-        # each request flushes its attach→detach delta (O(1) per request,
-        # not O(slots) per tick) in _flush_share.
-        inv = 1.0 / self.active
-        self._share_ticks += 1
-        self._share_device_ms += 1e3 * device_s * inv
-        self._share_transfer += transfer * inv
-        if spec_w is not None:
-            d_ms = 1e3 * device_s * self.engine.draft_share
-            self._share_draft_ms += d_ms * inv
-            self._share_verify_ms += (1e3 * device_s - d_ms) * inv
-        self.decode_device_ms_total += 1e3 * device_s
-        now = time.perf_counter()
-        free_later: List[int] = []
-        for b, req in enumerate(self.slots):
-            if req is None:
-                continue
-            if draws is not None:
-                # row b emitted its accepted window (the last entry is
-                # the verify forward's own draw: correction token on a
-                # rejection, bonus token on full acceptance)
-                emitted = [int(t) for t in draws[b, :counts[b]]]
-                if self._spec_on[b]:
-                    req.spec_proposed += spec_w - 1
-                    req.spec_accepted += int(counts[b]) - 1
-                    if req.trace is not None:
-                        req.trace.bump("spec_proposed", spec_w - 1)
-                        req.trace.bump("spec_accepted",
-                                       int(counts[b]) - 1)
-            elif tokens is not None:
-                emitted = [int(tokens[b])]
-            else:
-                emitted = [int(greedy[b]) if host is None
-                           else req.sampler.sample(host[b])]
-            reason = None
-            for t in emitted:
-                self._record_token(req, t, now)
-                reason = self._finish_reason(req, t)
-                if reason is not None:
-                    # mid-window finish: the device advanced the full
-                    # accepted count, but the slot frees below and the
-                    # next admission re-uploads state — the extra
-                    # positions are never attended
-                    self._finish(req, reason, now)
-                    finished.append(req)
-                    free_later.append(b)
+            device_s = time.perf_counter() - t_dev
+        with phase("emit", tick=tick):
+            self.steps += 1
+            self.decode_ticks += 1
+            self.decode_transfer_bytes += transfer
+            if self.speculative:
+                self._spec_account(spec_w, counts, device_s)
+            self._push(self.tick_transfer_window, transfer)
+            # per-request decode accounting rides as counters, not spans: a
+            # request may decode for thousands of ticks and a span per tick
+            # would defeat the bounded-trace design.  The per-tick device/
+            # transfer cost splits evenly across the slots that shared it —
+            # accumulated ONCE per tick into the cumulative share counters;
+            # each request flushes its attach→detach delta (O(1) per request,
+            # not O(slots) per tick) in _flush_share.
+            inv = 1.0 / self.active
+            self._share_ticks += 1
+            self._share_device_ms += 1e3 * device_s * inv
+            self._share_transfer += transfer * inv
+            if spec_w is not None:
+                d_ms = 1e3 * device_s * self.engine.draft_share
+                self._share_draft_ms += d_ms * inv
+                self._share_verify_ms += (1e3 * device_s - d_ms) * inv
+            self.decode_device_ms_total += 1e3 * device_s
+            now = time.perf_counter()
+            free_later: List[int] = []
+            for b, req in enumerate(self.slots):
+                if req is None:
+                    continue
+                if draws is not None:
+                    # row b emitted its accepted window (the last entry is
+                    # the verify forward's own draw: correction token on a
+                    # rejection, bonus token on full acceptance)
+                    emitted = [int(t) for t in draws[b, :counts[b]]]
+                    if self._spec_on[b]:
+                        req.spec_proposed += spec_w - 1
+                        req.spec_accepted += int(counts[b]) - 1
+                        if req.trace is not None:
+                            req.trace.bump("spec_proposed", spec_w - 1)
+                            req.trace.bump("spec_accepted",
+                                           int(counts[b]) - 1)
+                elif tokens is not None:
+                    emitted = [int(tokens[b])]
+                else:
+                    emitted = [int(greedy[b]) if host is None
+                               else req.sampler.sample(host[b])]
+                reason = None
+                for t in emitted:
+                    self._record_token(req, t, now)
+                    reason = self._finish_reason(req, t)
+                    if reason is not None:
+                        # mid-window finish: the device advanced the full
+                        # accepted count, but the slot frees below and the
+                        # next admission re-uploads state — the extra
+                        # positions are never attended
+                        self._finish(req, reason, now)
+                        finished.append(req)
+                        free_later.append(b)
+                        self._notify(req, t)
+                        break
                     self._notify(req, t)
-                    break
-                self._notify(req, t)
-            if reason is None:
-                self._last_token[b] = emitted[-1]
-                self._ctr[b] = len(req.output)
-                if self.paged:
-                    # mirror the device's per-row length advance for
-                    # continuing rows (no re-upload needed while nothing
-                    # else changes)
-                    self._lengths[b] += len(emitted)
-        if self.device_sampling and self._samp_dev is not None:
-            # no slot changed hands: next tick's inputs never leave the
-            # device (a finish this tick clears _samp_dev via the
-            # deferred _free_slot below, falling back to a host re-upload
-            # built from the mirrors)
-            self._tok_dev, self._ctr_dev = tok_dev, ctr_dev
-        self._push(self.device_ms_window, 1e3 * device_s)
-        self._push(self.prefill_ms_window, 1e3 * prefill_s)
-        host_ms = 1e3 * max(0.0, (time.perf_counter() - t_tick)
-                            - device_s - prefill_s)
-        self._push(self.host_ms_window, host_ms)
-        h = self.hist
-        h["decode_device_ms"].observe(1e3 * device_s)
-        h["decode_host_ms"].observe(host_ms)
-        h["prefill_ms"].observe(1e3 * prefill_s)
-        h["tick_transfer_bytes"].observe(transfer)
-        # ``inv`` is this tick's 1/active from before the token loop: the
-        # host cost is shared by the slots that decoded this tick.  Slots
-        # that finished are freed only BELOW, after this accrual, so a
-        # finishing request's flush still carries its final-tick share —
-        # per-request attribution sums to the global accumulators.
-        self._share_host_ms += host_ms * inv
-        self.decode_host_ms_total += host_ms
-        for b in free_later:
-            self._free_slot(b)
+                if reason is None:
+                    self._last_token[b] = emitted[-1]
+                    self._ctr[b] = len(req.output)
+                    if self.paged:
+                        # mirror the device's per-row length advance for
+                        # continuing rows (no re-upload needed while nothing
+                        # else changes)
+                        self._lengths[b] += len(emitted)
+            if self.device_sampling and self._samp_dev is not None:
+                # no slot changed hands: next tick's inputs never leave the
+                # device (a finish this tick clears _samp_dev via the
+                # deferred _free_slot below, falling back to a host re-upload
+                # built from the mirrors)
+                self._tok_dev, self._ctr_dev = tok_dev, ctr_dev
+            self._push(self.device_ms_window, 1e3 * device_s)
+            self._push(self.prefill_ms_window, 1e3 * prefill_s)
+            host_ms = 1e3 * max(0.0, (time.perf_counter() - t_tick)
+                                - device_s - prefill_s)
+            self._push(self.host_ms_window, host_ms)
+            h = self.hist
+            h["decode_device_ms"].observe(1e3 * device_s)
+            h["decode_host_ms"].observe(host_ms)
+            h["prefill_ms"].observe(1e3 * prefill_s)
+            h["tick_transfer_bytes"].observe(transfer)
+            # ``inv`` is this tick's 1/active from before the token loop: the
+            # host cost is shared by the slots that decoded this tick.  Slots
+            # that finished are freed only BELOW, after this accrual, so a
+            # finishing request's flush still carries its final-tick share —
+            # per-request attribution sums to the global accumulators.
+            self._share_host_ms += host_ms * inv
+            self.decode_host_ms_total += host_ms
+            for b in free_later:
+                self._free_slot(b)
         return finished
 
     def run(self, max_steps: int = 10_000) -> List[Request]:
@@ -1617,7 +1651,11 @@ class SchedulerService:
             dev_ms = sorted(s.device_ms_window)
             pre_ms = sorted(s.prefill_ms_window)
             xfer = sorted(s.tick_transfer_window)
+            phases = s.phases.stats()
             h = s.hist
+            # device_ms_* is the host clock from the decode dispatch to the
+            # fetched ids (dispatch plus fetch), not device time; the
+            # usage ledger conserves against device_ms_total under that key
             decode = {
                 "device_sampling": s.device_sampling,
                 "ticks": s.decode_ticks,
@@ -1634,6 +1672,9 @@ class SchedulerService:
                 "prefill_s_total": s.prefill_s_total,
                 "device_ms_total": s.decode_device_ms_total,
                 "host_ms_total": s.decode_host_ms_total,
+                **phases,
+                "dispatch_ms": _per(phases, "dispatch"),
+                "fetch_ms": _per(phases, "fetch"),
                 "decode_tokens_total": s.decode_tokens_total,
                 "prefill_tokens_total": s.prefill_tokens_total,
                 "compiled_steps": s.engine.decode_cache_size(),
@@ -1716,37 +1757,50 @@ class SchedulerService:
             s._state_dirty = True
 
     def _run(self) -> None:
+        # the driver's thread is tiled by the scheduler's phases: ``loop``
+        # holds whatever the ``wait`` and the tick's own phases leave
+        phase = self.scheduler.phases.phase
         while True:
-            with self._lock:
-                while not self._closed and self.scheduler.idle():
-                    # parked requests keep the scheduler idle; their
-                    # deadlines are still enforced on this slow tick
-                    for req in self.scheduler.reap_parked_expired():
-                        if req.req_id in self._events:
-                            self._events.pop(req.req_id).set()
-                    self._work.wait(timeout=0.1)
-                if self._closed:
-                    self._fail_in_flight(RuntimeError(
-                        "scheduler service closed with requests in flight"))
-                    return
-                try:
-                    t0 = time.monotonic()
-                    finished = self.scheduler.step()
-                    now = time.monotonic()
-                    self.last_tick_s = now - t0
-                    self.last_step_at = now
-                    self.consecutive_errors = 0
-                    events = [self._events.pop(r.req_id) for r in finished
-                              if r.req_id in self._events]
-                except BaseException as err:  # noqa: BLE001 — keep driving
-                    # Fail every in-flight request but keep the driver
-                    # alive: a poisoned batch must not hang future ones.
-                    # The error counters feed the replica health monitor's
-                    # consecutive-error scoring.
-                    self.driver_errors += 1
-                    self.consecutive_errors += 1
-                    self.last_error = err
-                    self._fail_in_flight(err)
-                    continue
-            for ev in events:
-                ev.set()
+            with phase("loop"):
+                with self._lock:
+                    if not self._closed and self.scheduler.idle():
+                        with phase("wait"):
+                            self._wait_for_work()
+                    if self._closed:
+                        self._fail_in_flight(RuntimeError(
+                            "scheduler service closed with requests in "
+                            "flight"))
+                        return
+                    try:
+                        t0 = time.monotonic()
+                        finished = self.scheduler.step()
+                        now = time.monotonic()
+                        self.last_tick_s = now - t0
+                        self.last_step_at = now
+                        self.consecutive_errors = 0
+                        events = [self._events.pop(r.req_id)
+                                  for r in finished
+                                  if r.req_id in self._events]
+                    except BaseException as err:  # noqa: BLE001
+                        # Fail every in-flight request but keep the driver
+                        # alive: a poisoned batch must not hang future
+                        # ones.  The error counters feed the replica health
+                        # monitor's consecutive-error scoring.
+                        self.driver_errors += 1
+                        self.consecutive_errors += 1
+                        self.last_error = err
+                        self._fail_in_flight(err)
+                        continue
+                for ev in events:
+                    ev.set()
+
+    def _wait_for_work(self) -> None:
+        """Sleep on the work condition (lock held) until a request is
+        pending or the service closes."""
+        while not self._closed and self.scheduler.idle():
+            # parked requests keep the scheduler idle; their deadlines
+            # are still enforced on this slow tick
+            for req in self.scheduler.reap_parked_expired():
+                if req.req_id in self._events:
+                    self._events.pop(req.req_id).set()
+            self._work.wait(timeout=0.1)

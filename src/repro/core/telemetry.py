@@ -18,9 +18,24 @@ list" pattern that previously backed /metrics percentiles:
     previous trimmed windows kept the most recent 2-4k samples — a bound,
     but a biased one; the reservoir's bound is explicit and unbiased.
 
+And the phase mechanism that puts host work on the device trace's clock:
+
+  * ``span`` — a named host span (``flexserve.<name>``) on the profiler's
+    clock.  It is a TraceMe: about a microsecond when no capture is
+    active, so spans stay on permanently.
+  * ``PhaseClock`` — the phases one owner (the scheduler's driver, the
+    coalescer's dispatcher, the HTTP handlers) spends its thread's time
+    in.  Each phase is a ``span`` named ``flexserve.<owner>.<phase>`` and
+    adds its host milliseconds and a count to the owner's lifetime
+    counters, so a gap in the device trace can be put down to the phase
+    that covered it, and the counters give the same split without one.
+
 This module lives in ``repro.core`` (not ``repro.serving``) because the
 scheduler — a core component — feeds these directly; the serving-plane
-tracer builds on top in ``repro.serving.telemetry``.
+tracer builds on top in ``repro.serving.telemetry``.  Both read
+``time.perf_counter``, so a request's ``Trace`` (whose ``trace_id`` the
+request-scoped phases carry as span metadata) and the phases share a
+clock.
 """
 
 from __future__ import annotations
@@ -28,7 +43,10 @@ from __future__ import annotations
 import math
 import random
 import threading
+import time
 from typing import Any, Dict, List, Optional, Sequence
+
+import jax
 
 
 def pctl(sorted_vals: Sequence[float], p: float) -> float:
@@ -183,3 +201,91 @@ class Reservoir:
     def __len__(self) -> int:
         with self._lock:
             return len(self.samples)
+
+
+def span(name: str, **meta: Any) -> jax.profiler.TraceAnnotation:
+    """Host span ``flexserve.<name>`` on the profiler's clock; ``meta``
+    rides as TraceMe metadata (a request's ``trace_id``, a tick number)."""
+    return jax.profiler.TraceAnnotation(f"flexserve.{name}", **meta)
+
+
+class PhaseClock:
+    """The phases that tile one owner's thread time.
+
+    ``with clock.phase("emit", tick=n):`` opens the span
+    ``flexserve.<owner>.emit`` and adds the phase's host time (one pair of
+    ``perf_counter_ns`` reads) and one count to the owner's lifetime
+    counters.  A phase entered while another phase of the same clock is
+    open on this thread suspends it: the outer span ends and its time
+    stops counting until the inner phase exits, when it resumes as a new
+    span.  So the phases never overlap, every nanosecond inside the
+    outermost phase belongs to exactly one of them, and the counters are
+    each phase's own (self) time.  Spans of other code (the engine's)
+    nest inside a phase as usual.
+
+    Counters are read as differences: ``stats()`` returns
+    ``phase_ms_total`` and ``phase_count`` keyed by every phase named at
+    construction, zero until it runs."""
+
+    def __init__(self, owner: str, phases: Sequence[str]):
+        self.owner = owner
+        self._labels = {p: f"flexserve.{owner}.{p}" for p in phases}
+        self._ns = dict.fromkeys(phases, 0)
+        self._count = dict.fromkeys(phases, 0)
+        self._lock = threading.Lock()
+        self._open = _OpenPhase()            # innermost open phase
+
+    def phase(self, name: str, **meta: Any) -> "_Phase":
+        if name not in self._labels:
+            raise KeyError(f"{self.owner} has no phase {name!r}; "
+                           f"phases: {sorted(self._labels)}")
+        return _Phase(self, name, meta)
+
+    def stats(self) -> Dict[str, Dict[str, float]]:
+        with self._lock:
+            return {"phase_ms_total": {k: v / 1e6
+                                       for k, v in self._ns.items()},
+                    "phase_count": dict(self._count)}
+
+
+class _OpenPhase(threading.local):
+    phase: Optional["_Phase"] = None
+
+
+class _Phase:
+    __slots__ = ("clock", "name", "meta", "outer", "span", "t0")
+
+    def __init__(self, clock: PhaseClock, name: str, meta: Dict[str, Any]):
+        self.clock = clock
+        self.name = name
+        self.meta = meta
+
+    def _begin(self, now: int) -> None:
+        self.t0 = now
+        self.span = jax.profiler.TraceAnnotation(
+            self.clock._labels[self.name], **self.meta)
+        self.span.__enter__()
+
+    def _end(self, now: int, count: int) -> None:
+        self.span.__exit__(None, None, None)
+        clock = self.clock
+        with clock._lock:
+            clock._ns[self.name] += now - self.t0
+            clock._count[self.name] += count
+
+    def __enter__(self) -> "_Phase":
+        now = time.perf_counter_ns()
+        opened = self.clock._open
+        outer = self.outer = opened.phase
+        if outer is not None:
+            outer._end(now, 0)
+        opened.phase = self
+        self._begin(now)
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        now = time.perf_counter_ns()
+        self._end(now, 1)
+        outer = self.clock._open.phase = self.outer
+        if outer is not None:
+            outer._begin(now)

@@ -123,13 +123,17 @@ GET  /healthz      -> 200 {"status": "ready", "replicas": {...}}
                        the payload aggregates per-replica health: ready
                        count + cordoned list — so external LBs stop
                        routing to a dead pool)
-GET  /metrics      -> {"uptime_s", "requests", "routes": {...},
+GET  /metrics      -> {"uptime_s", "requests",
+                       "routes": {"<METHOD> <path>": {count, mean_ms,
+                                  max_ms, residence_ms_total}},
+                       "http": {phase_ms_total, phase_count},
                        "coalesce": {batches_formed, rows_total,
                                     mean_rows_per_batch, max_rows_per_batch,
                                     queue_wait_p50_ms, queue_wait_p95_ms,
                                     queue_wait_ms_hist, forward_ms_hist,
                                     adaptive_linger, effective_linger_ms,
-                                    ewma_interarrival_ms},
+                                    ewma_interarrival_ms,
+                                    phase_ms_total, phase_count},
                        "ensemble_compiles": {"<bucket>": count, ...},
                        "admission": {max_queue, bulk_max,
                                      default_deadline_ms,
@@ -153,6 +157,10 @@ GET  /metrics      -> {"uptime_s", "requests", "routes": {...},
                                              prefill_forwards,
                                              prefill_requests,
                                              prefill_s_total,
+                                             device_ms_total,
+                                             host_ms_total,
+                                             phase_ms_total, phase_count,
+                                             dispatch_ms, fetch_ms,
                                              compiled_steps,
                                              host_ms_hist, device_ms_hist,
                                              prefill_ms_hist,
@@ -200,6 +208,22 @@ GET  /metrics      -> {"uptime_s", "requests", "routes": {...},
                                  (zeroed without --fault-config),
                        "telemetry": {capacity, in_flight, completed,
                                      completed_total, leaked_total}}
+
+    A route's ``mean_ms``/``max_ms`` time ``FlexServeApp.handle``;
+    ``residence_ms_total`` sums each request's time in the server, from
+    its first byte read to its last byte written (a stream's last chunk).
+
+    ``phase_ms_total``/``phase_count`` are lifetime host milliseconds and
+    counts of the phases that tile a thread's time, read as differences
+    (``repro.core.telemetry.PhaseClock``; every phase is also a
+    ``flexserve.<owner>.<phase>`` span in a profiler capture):
+    ``http`` read / handle / respond / write over every handler thread;
+    ``coalesce`` idle / linger / assemble / forward / fetch / scatter on
+    the dispatch thread; ``decode`` wait / reap / admit / dispatch / fetch
+    / emit / loop on the scheduler's driver.  ``dispatch_ms`` and
+    ``fetch_ms`` are the mean per tick.  ``device_ms_*`` is host-clock
+    time from the decode dispatch to the fetched ids (dispatch plus
+    fetch), not device time: a profiler capture gives the device's.
 
     ``*_hist`` values are fixed-bucket histogram snapshots:
     {"le": [bounds..., "+Inf"], "counts": [cumulative...], "count", "sum",

@@ -33,8 +33,16 @@ from typing import Any, Callable, Dict, Hashable, List, Optional, Sequence
 import numpy as np
 
 from repro.core.batching import BucketSpec
-from repro.core.telemetry import Histogram, Reservoir
+from repro.core.telemetry import Histogram, PhaseClock, Reservoir
 from repro.serving.admission import DeadlineError
+
+# phases of the dispatch thread (``flexserve.coalesce.<phase>`` spans):
+# waiting for a request with no group open (``idle``) or with one open
+# (``linger``); ``assemble`` (group bookkeeping, the expiry filter, the
+# concatenation), ``forward`` (the call), ``fetch`` (the outputs to host:
+# waits for the device), ``scatter`` (per-request slices, stats, wake-ups)
+COALESCE_PHASES = ("idle", "linger", "assemble", "forward", "fetch",
+                   "scatter")
 
 
 @dataclass
@@ -154,6 +162,7 @@ class BatchCoalescer:
         self._open_groups = 0
         self._deadline_dropped = 0
         self._ewma_fwd_s: Optional[float] = None
+        self.phases = PhaseClock("coalesce", COALESCE_PHASES)
         self._thread = threading.Thread(target=self._run, daemon=True,
                                         name="flexserve-coalescer")
         self._thread.start()
@@ -252,6 +261,7 @@ class BatchCoalescer:
                 "effective_linger_ms": 1e3 * effective_linger,
                 "ewma_interarrival_ms": (1e3 * gap if gap is not None
                                          else None),
+                **self.phases.stats(),
             }
 
     # --- dispatch thread ------------------------------------------------------
@@ -272,51 +282,59 @@ class BatchCoalescer:
         return g.deadline
 
     def _run(self) -> None:
+        # the dispatch thread is tiled by the phases: ``assemble`` holds
+        # each turn of the loop, and the wait for a request and
+        # ``_execute``'s phases suspend it while they run
+        phase = self.phases.phase
         groups: Dict[Any, _Group] = {}
         while True:
-            now = time.perf_counter()
-            for sig in list(groups):           # flush expired sub-queues
-                if self._effective_deadline(groups[sig], now) <= now:
-                    self._execute(groups.pop(sig).entries)
-            if groups:
-                timeout = max(
-                    min(self._effective_deadline(g, now) - now
-                        for g in groups.values()), 0.0)
-            else:
-                timeout = 0.1                  # idle poll for the sentinel
-            with self._stats_lock:
-                self._open_groups = len(groups)
-            try:
-                entry = self._queue.get(timeout=timeout)
-            except queue.Empty:
-                if self._closed and not groups:
+            with phase("assemble"):
+                now = time.perf_counter()
+                for sig in list(groups):       # flush expired sub-queues
+                    if self._effective_deadline(groups[sig], now) <= now:
+                        self._execute(groups.pop(sig).entries)
+                if groups:
+                    timeout = max(
+                        min(self._effective_deadline(g, now) - now
+                            for g in groups.values()), 0.0)
+                else:
+                    timeout = 0.1              # idle poll for the sentinel
+                with self._stats_lock:
+                    self._open_groups = len(groups)
+                try:
+                    with phase("linger" if groups else "idle"):
+                        entry = self._queue.get(timeout=timeout)
+                except queue.Empty:
+                    if self._closed and not groups:
+                        break
+                    continue
+                if entry is None:              # close sentinel
+                    for g in groups.values():  # serve what we have
+                        self._execute(g.entries)
                     break
-                continue
-            if entry is None:                  # close sentinel
-                for g in groups.values():      # serve what we have
-                    self._execute(g.entries)
-                break
-            now = time.perf_counter()          # get() may have blocked long
-            sig = entry.signature()
-            g = groups.get(sig)
-            if g is not None and g.rows + entry.n > self.max_rows:
-                self._execute(groups.pop(sig).entries)   # full: flush, restart
-                g = None
-            if g is None:
-                groups[sig] = g = _Group(entry, now + self.linger_s())
-            else:
-                g.entries.append(entry)
-                g.rows += entry.n
-            if entry.ctx is not None and entry.ctx.deadline_s is not None:
-                # a deadline-carrying entry must not rot in a half-filled
-                # group past the moment it could still be served: flush one
-                # forward's worth of time BEFORE the deadline so dispatch
-                # happens while the entry is still live
-                g.deadline = min(g.deadline,
-                                 max(entry.ctx.deadline_s
-                                     - self._fwd_margin_s(), now))
-            if g.rows >= self.max_rows:
-                self._execute(groups.pop(sig).entries)
+                now = time.perf_counter()      # get() may have blocked long
+                sig = entry.signature()
+                g = groups.get(sig)
+                if g is not None and g.rows + entry.n > self.max_rows:
+                    # full: flush, restart
+                    self._execute(groups.pop(sig).entries)
+                    g = None
+                if g is None:
+                    groups[sig] = g = _Group(entry, now + self.linger_s())
+                else:
+                    g.entries.append(entry)
+                    g.rows += entry.n
+                if (entry.ctx is not None
+                        and entry.ctx.deadline_s is not None):
+                    # a deadline-carrying entry must not rot in a half-
+                    # filled group past the moment it could still be
+                    # served: flush one forward's worth of time BEFORE the
+                    # deadline so dispatch happens while it is still live
+                    g.deadline = min(g.deadline,
+                                     max(entry.ctx.deadline_s
+                                         - self._fwd_margin_s(), now))
+                if g.rows >= self.max_rows:
+                    self._execute(groups.pop(sig).entries)
         self._drain_on_close()
 
     def _fwd_margin_s(self) -> float:
@@ -358,38 +376,42 @@ class BatchCoalescer:
                 tr.span("coalesce_queue", e.enqueued_at, now, rows=e.n)
                 tr.event("coalesce_group", t=now, rows=rows,
                          requests=len(group))
+        phase = self.phases.phase
+        out_np = None
         try:
             if group:
                 merged = {k: np.concatenate([e.batch[k] for e in group])
                           for k in group[0].batch}
                 t_fwd = time.perf_counter()
-                if self._fwd_nparams >= 3:
-                    out = self._forward(merged, group[0].tag,
-                                        [e.ctx for e in group])
-                elif self._fwd_nparams == 2:
-                    out = self._forward(merged, group[0].tag)
-                else:
-                    out = self._forward(merged)
-                out_np = _tree_to_numpy(out)
+                with phase("forward", rows=rows):
+                    if self._fwd_nparams >= 3:
+                        out = self._forward(merged, group[0].tag,
+                                            [e.ctx for e in group])
+                    elif self._fwd_nparams == 2:
+                        out = self._forward(merged, group[0].tag)
+                    else:
+                        out = self._forward(merged)
+                with phase("fetch", rows=rows):
+                    out_np = _tree_to_numpy(out)
                 fwd_s = time.perf_counter() - t_fwd
+        except BaseException as err:       # noqa: BLE001 — scattered to callers
+            for e in group:
+                e.error = err
+        with phase("scatter", rows=rows):
+            if out_np is not None:
                 with self._stats_lock:
                     self._ewma_fwd_s = (
                         fwd_s if self._ewma_fwd_s is None else
                         0.8 * self._ewma_fwd_s + 0.2 * fwd_s)
                 self._fwd_hist.observe(1e3 * fwd_s)
+                off = 0
                 for e in group:
                     tr = getattr(e.ctx, "trace", None)
                     if tr is not None:
                         tr.span("coalesce_forward", t_fwd, t_fwd + fwd_s,
                                 rows=rows)
-                off = 0
-                for e in group:
                     e.result = _tree_slice(out_np, off, off + e.n)
                     off += e.n
-        except BaseException as err:       # noqa: BLE001 — scattered to callers
-            for e in group:
-                e.error = err
-        finally:
             with self._stats_lock:
                 if group:
                     self._batches += 1

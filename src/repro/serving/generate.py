@@ -58,7 +58,8 @@ from repro.core.engine import GenerationResult, InferenceEngine
 from repro.core.faults import FaultInjector
 from repro.core.sampling import SamplingParams
 from repro.core.scheduler import (Request, SchedulerBusy, SchedulerService,
-                                  ZERO_PAGER_STATS, ZERO_SPECULATION_STATS)
+                                  ZERO_PAGER_STATS, ZERO_SPECULATION_STATS,
+                                  zero_phase_stats)
 from repro.core.telemetry import BYTES_BUCKETS, Histogram
 from repro.serving.admission import RequestContext, ShedError
 from repro.serving.replica import (CORDONED, READY, ReplicaPool,
@@ -628,6 +629,7 @@ class GenerationService:
                                "prefill_s_total": 0.0,
                                "device_ms_total": 0.0,
                                "host_ms_total": 0.0,
+                               **zero_phase_stats(),
                                "decode_tokens_total": 0,
                                "prefill_tokens_total": 0,
                                "compiled_steps": None,

@@ -787,6 +787,9 @@ class ReplicaPool:
             bd, ed = base.get("decode", {}), extra.get("decode", {})
             for k in self._DECODE_SUM_KEYS:
                 bd[k] = bd.get(k, 0) + ed.get(k, 0)
+            for k in ("phase_ms_total", "phase_count"):
+                for p, v in ed.get(k, {}).items():
+                    bd[k][p] = bd[k].get(p, 0) + v
         base["max_pending"] = self.max_pending
         base["replicas"] = self.summary()
         return base
@@ -796,7 +799,8 @@ def _zero_service_stats() -> Dict[str, Any]:
     """SchedulerService.stats() schema with zero traffic — the fallback
     when every replica's driver is wedged mid-stall."""
     from repro.core.scheduler import (ZERO_PAGER_STATS,
-                                      ZERO_SPECULATION_STATS)
+                                      ZERO_SPECULATION_STATS,
+                                      zero_phase_stats)
     snap = Histogram().snapshot
     decode = {
         "device_sampling": True, "ticks": 0,
@@ -806,7 +810,8 @@ def _zero_service_stats() -> Dict[str, Any]:
         "transfer_bytes_total": 0, "prefill_transfer_bytes_total": 0,
         "prefill_forwards": 0, "prefill_requests": 0,
         "prefill_s_total": 0.0, "device_ms_total": 0.0,
-        "host_ms_total": 0.0, "decode_tokens_total": 0,
+        "host_ms_total": 0.0, **zero_phase_stats(),
+        "decode_tokens_total": 0,
         "prefill_tokens_total": 0, "compiled_steps": 0,
         "host_ms_hist": snap(), "device_ms_hist": snap(),
         "prefill_ms_hist": snap(), "transfer_bytes_hist": snap(),

@@ -41,6 +41,7 @@ from repro.core.faults import (ZERO_FAULT_STATS, FaultInjector,
 from repro.core.registry import ModelRegistry
 from repro.core.slo import (ZERO_SLO, SLIStore, SLOController, UsageLedger,
                             load_policies)
+from repro.core.telemetry import PhaseClock
 from repro.serving import api
 from repro.serving.admission import (AdmissionController, DeadlineError,
                                      RequestContext, ShedError)
@@ -51,6 +52,13 @@ from repro.serving.modelstore import StoreError
 from repro.serving.replica import ZERO_REPLICA_STATS
 from repro.serving.telemetry import (DeviceProfiler, FlightRecorder,
                                      prometheus_exposition)
+
+# phases of an HTTP handler thread (``flexserve.http.<phase>`` spans):
+# ``read`` (request line to body), ``handle`` (routing, and the wait on the
+# coalescer or the scheduler), ``respond`` (logits to the response, JSON
+# encoding), ``write`` (the socket write, or each stream chunk; a stream's
+# waits between chunks are in no phase)
+HTTP_PHASES = ("read", "handle", "respond", "write")
 
 # lifecycle section served when no manager is attached, so the /metrics
 # key set (and the Prometheus exposition) is identical either way
@@ -143,6 +151,7 @@ class FlexServeApp:
         self._closing = False
         self._route_stats: Dict[str, Dict[str, float]] = {}
         self._stats_lock = threading.Lock()
+        self.phases = PhaseClock("http", HTTP_PHASES)
         # the generate plane is budgeted in TOKEN units (prompt length +
         # requested max_new_tokens): a single huge request can't slip in
         # as "one row".  Default scales the row budget by a typical
@@ -323,10 +332,20 @@ class FlexServeApp:
             with self._stats_lock:
                 st = self._route_stats.setdefault(
                     self._stats_key(method, path),
-                    {"count": 0, "total_s": 0.0, "max_s": 0.0})
+                    {"count": 0, "total_s": 0.0, "max_s": 0.0,
+                     "residence_s": 0.0})
                 st["count"] += 1
                 st["total_s"] += dt
                 st["max_s"] = max(st["max_s"], dt)
+
+    def record_residence(self, method: str, path: str,
+                         seconds: float) -> None:
+        """Add one request's residence (its first byte read to its last
+        byte written, measured by the HTTP handler) to its route."""
+        with self._stats_lock:
+            st = self._route_stats.get(self._stats_key(method, path))
+            if st is not None:
+                st["residence_s"] += seconds
 
     def _route(self, method: str, path: str, body: bytes,
                headers: Optional[Dict[str, str]] = None,
@@ -532,12 +551,14 @@ class FlexServeApp:
             routes = {
                 k: {"count": v["count"],
                     "mean_ms": 1e3 * v["total_s"] / max(v["count"], 1),
-                    "max_ms": 1e3 * v["max_s"]}
+                    "max_ms": 1e3 * v["max_s"],
+                    "residence_ms_total": 1e3 * v["residence_s"]}
                 for k, v in self._route_stats.items()}
             requests = self.request_count
         out = {"uptime_s": time.monotonic() - self._t0,
                "started_unix": self._started_unix,
-               "requests": requests, "routes": routes}
+               "requests": requests, "routes": routes,
+               "http": self.phases.stats()}
         if self.coalescer is not None:
             out["coalesce"] = self.coalescer.stats()
         if self.ensemble is not None:
@@ -761,7 +782,8 @@ class FlexServeApp:
         policy = req.get("policy", "soft_vote")
         logits = self._ensemble_logits(batch, alias, ctx)
         try:
-            return ens.respond_from_logits(logits, policy=policy)
+            with self.phases.phase("respond", trace_id=ctx.trace_id):
+                return ens.respond_from_logits(logits, policy=policy)
         except (KeyError, ValueError) as e:
             raise api.ApiError(400, str(e)) from None
 
@@ -899,12 +921,29 @@ def make_handler(app: FlexServeApp):
                 pass                          # client went away
 
         def _one_request(self) -> bool:
-            line = self.rfile.readline(65537)
+            line = self.rfile.readline(65537)   # idle keep-alive: no phase
             if not line or line in (b"\r\n", b"\n"):
                 return False
+            t_first = time.perf_counter()
+            with app.phases.phase("read"):
+                req = self._read(line)
+            if req is None:
+                return False
+            method, path, body, plane, keep = req
+            tid = (plane or {}).get("x-request-id")
+            try:
+                return self._respond(method, path, body, plane, keep, tid)
+            finally:
+                app.record_residence(method, path,
+                                     time.perf_counter() - t_first)
+
+        def _read(self, line: bytes):
+            """The rest of one request after its request line: ``(method,
+            path, body, plane headers, keep-alive)``, or None after
+            answering a malformed one."""
             parts = line.split()
             if len(parts) < 2:
-                return False
+                return None
             method, path = parts[0].decode("latin-1"), \
                 parts[1].decode("latin-1")
             length, keep = 0, True
@@ -924,7 +963,7 @@ def make_handler(app: FlexServeApp):
                             api.encode_response(api.error_body(api.ApiError(
                                 400, "bad Content-Length"))),
                             False)
-                        return False
+                        return None
                 elif key == b"connection":
                     keep = b"close" not in val.lower()
                 elif key in _PLANE_HEADERS:
@@ -933,29 +972,43 @@ def make_handler(app: FlexServeApp):
                     plane[key.decode("latin-1")] = \
                         val.strip().decode("latin-1")
             body = self.rfile.read(length) if length else b""
+            return method, path, body, plane, keep
+
+        def _respond(self, method: str, path: str, body: bytes,
+                     plane: Optional[Dict[str, str]], keep: bool,
+                     tid: Optional[str]) -> bool:
+            phase = app.phases.phase
+            meta = {"trace_id": tid} if tid else {}
             extra = None
-            try:
-                status, payload = 200, app.handle(method, path, body, plane)
-            except api.ApiError as e:
-                status, extra = e.status, e.headers
-                payload = api.error_body(e)
-            except Exception as e:          # noqa: BLE001 — server boundary
-                status = 500
-                payload = api.error_body(
-                    api.ApiError(500, f"{type(e).__name__}: {e}"))
+            with phase("handle", **meta):
+                try:
+                    status, payload = 200, app.handle(method, path, body,
+                                                      plane)
+                except api.ApiError as e:
+                    status, extra = e.status, e.headers
+                    payload = api.error_body(e)
+                except Exception as e:      # noqa: BLE001 — server boundary
+                    status = 500
+                    payload = api.error_body(
+                        api.ApiError(500, f"{type(e).__name__}: {e}"))
+            headers = getattr(payload, "headers", None) or extra or {}
+            tid = headers.get("X-Request-Id", tid)
+            meta = {"trace_id": tid} if tid else {}
             if isinstance(payload, api.StreamingResponse):
-                return self._stream_reply(payload, keep)
-            ctype = "application/json"
-            if isinstance(payload, api.PlainTextResponse):
-                status, ctype = payload.status, payload.content_type
-                data = payload.text.encode("utf-8")
-            elif isinstance(payload, api.JsonResponse):
-                status = payload.status
-                extra = {**payload.headers, **(extra or {})}
-                data = api.encode_response(payload.payload)
-            else:
-                data = api.encode_response(payload)
-            self._reply(status, data, keep, extra, ctype)
+                return self._stream_reply(payload, keep, meta)
+            with phase("respond", **meta):
+                ctype = "application/json"
+                if isinstance(payload, api.PlainTextResponse):
+                    status, ctype = payload.status, payload.content_type
+                    data = payload.text.encode("utf-8")
+                elif isinstance(payload, api.JsonResponse):
+                    status = payload.status
+                    extra = {**payload.headers, **(extra or {})}
+                    data = api.encode_response(payload.payload)
+                else:
+                    data = api.encode_response(payload)
+            with phase("write", **meta):
+                self._reply(status, data, keep, extra, ctype)
             return keep
 
         def _reply(self, status: int, data: bytes, keep: bool,
@@ -970,13 +1023,17 @@ def make_handler(app: FlexServeApp):
                     f"\r\n").encode("latin-1")
             self.wfile.write(head + data)     # one syscall, one segment
 
-        def _stream_reply(self, resp: api.StreamingResponse,
-                          keep: bool) -> bool:
+        def _stream_reply(self, resp: api.StreamingResponse, keep: bool,
+                          meta: Dict[str, str]) -> bool:
             """Write a token stream as chunked transfer encoding — one
             NDJSON event per chunk, flushed as it decodes, so the client
             sees the first token long before the stream finishes.  A
             failed write means the client went away: cancel the request
-            (freeing its decode slot) and drop the connection."""
+            (freeing its decode slot) and drop the connection.  Each
+            chunk (encoding and socket write) is a ``write`` phase; the
+            waits for the scheduler between chunks are left out, so a
+            stream's handler pays one phase per token."""
+            phase = app.phases.phase
             lines = "".join(f"{k}: {v}\r\n"
                             for k, v in resp.headers.items())
             head = (f"HTTP/1.1 200 OK\r\n"
@@ -986,17 +1043,23 @@ def make_handler(app: FlexServeApp):
                     f"Connection: {'keep-alive' if keep else 'close'}\r\n"
                     f"\r\n").encode("latin-1")
             try:
-                self.wfile.write(head)
+                with phase("write", **meta):
+                    self.wfile.write(head)
                 for event in resp.events:
-                    if app.faults is not None:
-                        # "socket_drop": the connection dies mid-stream —
-                        # same teardown path as a real failed write
-                        app.faults.fire("socket_drop")
-                    data = api.encode_response(event) + b"\n"
-                    # chunk = size line + payload (wfile is unbuffered:
-                    # one write, one segment — the flush per token)
-                    self.wfile.write(b"%x\r\n%s\r\n" % (len(data), data))
-                self.wfile.write(b"0\r\n\r\n")
+                    with phase("write", **meta):
+                        if app.faults is not None:
+                            # "socket_drop": the connection dies mid-
+                            # stream — same teardown path as a real failed
+                            # write
+                            app.faults.fire("socket_drop")
+                        data = api.encode_response(event) + b"\n"
+                        # chunk = size line + payload (wfile is
+                        # unbuffered: one write, one segment — the flush
+                        # per token)
+                        self.wfile.write(b"%x\r\n%s\r\n"
+                                         % (len(data), data))
+                with phase("write", **meta):
+                    self.wfile.write(b"0\r\n\r\n")
                 return keep
             except InjectedFault:
                 resp.disconnect()             # cancel: free the decode slot
