@@ -101,9 +101,13 @@ def state_specs(state_sds, cfg: ModelConfig, mesh: Mesh):
         if name == "length":
             return P(*spec)
         if name in _SEQ_CACHE_NAMES and rank >= 4:
-            # (..., B, S, K, hd)
-            b_ax, s_ax, k_ax = rank - 4, rank - 3, rank - 2
-            B, K = leaf.shape[b_ax], leaf.shape[k_ax]
+            if rank == 4:   # stacked dense (L, B, S, K*hd): heads flat, minor
+                b_ax, s_ax, k_ax = 1, 2, 3
+                K = cfg.num_kv_heads
+            else:           # (..., B, S, K, hd)
+                b_ax, s_ax, k_ax = rank - 4, rank - 3, rank - 2
+                K = leaf.shape[k_ax]
+            B = leaf.shape[b_ax]
             if B % dsize == 0 and B >= dsize:
                 spec[b_ax] = batch_lead
                 if K % msize == 0 and K >= msize:

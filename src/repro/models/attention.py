@@ -6,7 +6,10 @@ All functions are pure-jnp reference paths; the Pallas kernels in
 swapped in by the engine when ``use_pallas=True``.
 
 Shapes: x (B, S, D); q (B, S, H, hd); k/v (B, S, K, hd); GQA groups G=H/K.
-KV caches are (B, Smax, K, hd) per layer with per-row valid ``lengths``.
+Per-layer KV caches are (B, Smax, K, hd) with per-row valid ``lengths``;
+the decoder-only transformer's stacked dense cache is (L, B, Smax, K*hd)
+(``init_kv_cache``), written in place one token per row and read one layer
+at a time as (B, Smax, K, hd).
 """
 
 from __future__ import annotations
@@ -173,8 +176,12 @@ def cache_dtype(cfg: ModelConfig):
 
 def init_kv_cache(num_layers: int, batch: int, max_len: int, cfg: ModelConfig,
                   dtype=None):
+    """Stacked dense cache: ``k``/``v`` are (L, B, Smax, K*hd).  With the
+    heads flattened into the minor dimension the stored layout is the one
+    the decode step reads whatever ``head_dim`` is (80 alone would pad to
+    128 lanes, and the layer loop would relayout each layer's slice)."""
     dt = dtype or cache_dtype(cfg)
-    shape = (num_layers, batch, max_len, cfg.num_kv_heads, cfg.head_dim)
+    shape = (num_layers, batch, max_len, cfg.num_kv_heads * cfg.head_dim)
     return {
         "k": jnp.zeros(shape, dt),
         "v": jnp.zeros(shape, dt),
@@ -191,6 +198,27 @@ def cache_write(cache_k, cache_v, new_k, new_v, lengths):
     ck = cache_k.at[rows, lengths].set(new_k[:, 0].astype(cache_k.dtype))
     cv = cache_v.at[rows, lengths].set(new_v[:, 0].astype(cache_v.dtype))
     return ck, cv
+
+
+def stacked_write(cache, layer, positions, new):
+    """Write new tokens into layer ``layer`` of a stacked cache, in place:
+    ``leaf[layer, b, positions[b, i]] = new[b, i]`` for every leaf.
+
+    cache leaves (L, B, Smax, C); positions (B, W); new leaves (B, W, ...)
+    holding C values a token.  Positions at or past Smax drop (jax scatter
+    default), as the dense cache has always done at its max_len boundary."""
+    B, W = positions.shape
+    rows = jnp.arange(B)[:, None]
+    return {name: leaf.at[layer, rows, positions].set(
+                new[name].reshape(B, W, -1).astype(leaf.dtype))
+            for name, leaf in cache.items()}
+
+
+def layer_kv(cache, layer, cfg: ModelConfig):
+    """Layer ``layer`` of the stacked dense cache as (B, Smax, K, hd)."""
+    _, B, Smax, _ = cache["k"].shape
+    shape = (B, Smax, cfg.num_kv_heads, cfg.head_dim)
+    return cache["k"][layer].reshape(shape), cache["v"][layer].reshape(shape)
 
 
 def ring_write(cache_k, cache_v, new_k, new_v, lengths, window: int):
@@ -283,6 +311,33 @@ def decode_attn_block(p, x1, layer_cache_k, layer_cache_v, lengths,
                                    window=window)
     out = out.reshape(B, 1, cfg.num_heads * cfg.head_dim)
     return out @ p["wo"] + p.get("bo", 0.0), ck, cv
+
+
+def stacked_decode_attn_block(p, x1, cache, layer, lengths,
+                              cfg: ModelConfig, *,
+                              window: Optional[int] = None):
+    """Single-token self-attention against layer ``layer`` of the stacked
+    dense cache (``init_kv_cache``), updated in place.
+
+    The token's K/V land at ``[layer, b, lengths[b]]`` (ring mode, Smax <=
+    window: at ``lengths % Smax``, as ``ring_write``); the layer is then
+    read through the same ``decode_attention_ref`` as ``decode_attn_block``
+    under the same mask.  x1: (B, 1, D).  Returns (out (B,1,D), cache)."""
+    B = x1.shape[0]
+    q, k, v = project_qkv(p, x1, cfg, positions=lengths[:, None])
+    Smax = cache["k"].shape[2]
+    ring = window is not None and Smax <= window
+    pos = lengths % Smax if ring else lengths
+    cache = stacked_write(cache, layer, pos[:, None], {"k": k, "v": v})
+    ck, cv = layer_kv(cache, layer, cfg)
+    if ring:
+        out = decode_attention_ref(q[:, 0], ck, cv,
+                                   ring_lengths(lengths, Smax))
+    else:
+        out = decode_attention_ref(q[:, 0], ck, cv, lengths + 1,
+                                   window=window)
+    out = out.reshape(B, 1, cfg.num_heads * cfg.head_dim)
+    return out @ p["wo"] + p.get("bo", 0.0), cache
 
 
 def cross_decode_attn_block(p, x1, kv_k, kv_v, cfg: ModelConfig,
@@ -381,20 +436,21 @@ def init_mla_cache(num_layers: int, batch: int, max_len: int,
     }
 
 
-def mla_decode_block(p, x1, c_cache, r_cache, lengths, cfg: ModelConfig):
+def mla_decode_block(p, x1, cache, layer, lengths, cfg: ModelConfig):
     """Absorbed-matrix MLA decode: attention in the latent (kv_lora) space.
 
-    x1 (B,1,D); c_cache (B,Smax,kvr); r_cache (B,Smax,rope).
-    Returns (out (B,1,D), new c_cache, new r_cache)."""
+    x1 (B,1,D); cache {"ckv": (L,B,Smax,kvr), "krope": (L,B,Smax,rope)},
+    written in place at ``[layer, b, lengths[b]]``.
+    Returns (out (B,1,D), cache)."""
     m = cfg.mla
     B = x1.shape[0]
     H = cfg.num_heads
     positions = lengths[:, None]
     q_nope, q_rope = _mla_q(p, x1, cfg, positions)       # (B,1,H,n),(B,1,H,r)
     c_kv, k_rope = _mla_ckv(p, x1, cfg, positions)       # (B,1,kvr),(B,1,r)
-    rows = jnp.arange(B)
-    c_cache = c_cache.at[rows, lengths].set(c_kv[:, 0])
-    r_cache = r_cache.at[rows, lengths].set(k_rope[:, 0])
+    cache = stacked_write(cache, layer, positions,
+                          {"ckv": c_kv, "krope": k_rope})
+    c_cache, r_cache = cache["ckv"][layer], cache["krope"][layer]
     # absorb W_UK into q: q_abs[b,h,c] = sum_n q_nope[b,h,n] * W_UK[c,h,n]
     kvb = p["kv_b"].reshape(m.kv_lora_rank, H, m.nope_head_dim + m.v_head_dim)
     w_uk = kvb[:, :, :m.nope_head_dim]                   # (kvr,H,n)
@@ -425,4 +481,4 @@ def mla_decode_block(p, x1, c_cache, r_cache, lengths, cfg: ModelConfig):
                              c_cache.astype(jnp.float32))
     out = jnp.einsum("bhc,chv->bhv", out_lat, w_uv.astype(jnp.float32))
     out = out.reshape(B, 1, H * m.v_head_dim).astype(x1.dtype)
-    return out @ p["wo"], c_cache, r_cache
+    return out @ p["wo"], cache

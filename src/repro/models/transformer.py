@@ -241,19 +241,15 @@ def init_state(cfg: ModelConfig, batch: int, max_len: int,
     return state
 
 
-def _layer_decode(cfg: ModelConfig, moe: bool, window, x, lp, cache_layer,
+def _layer_decode(cfg: ModelConfig, moe: bool, window, x, lp, cache, layer,
                   lengths):
     h = apply_norm(lp["ln1"], x, cfg)
     if cfg.attn_kind == "mla":
-        attn_out, ck, cr = attn.mla_decode_block(
-            lp["attn"], h, cache_layer["ckv"], cache_layer["krope"],
-            lengths, cfg)
-        new_cache = {"ckv": ck, "krope": cr}
+        attn_out, cache = attn.mla_decode_block(lp["attn"], h, cache, layer,
+                                                lengths, cfg)
     else:
-        attn_out, ck, cv = attn.decode_attn_block(
-            lp["attn"], h, cache_layer["k"], cache_layer["v"], lengths, cfg,
-            window=window)
-        new_cache = {"k": ck, "v": cv}
+        attn_out, cache = attn.stacked_decode_attn_block(
+            lp["attn"], h, cache, layer, lengths, cfg, window=window)
     if cfg.parallel_block:
         x = x + attn_out + apply_mlp(lp["mlp"], h, cfg)
     else:
@@ -264,18 +260,31 @@ def _layer_decode(cfg: ModelConfig, moe: bool, window, x, lp, cache_layer,
             x = x + mo
         else:
             x = x + apply_mlp(lp["mlp"], h2, cfg)
-    return x, new_cache
+    return x, cache
+
+
+def _scan_carried(layer_fn, stacked, cache, x):
+    """Scan ``layer_fn(x, lp, cache, layer) -> (x, cache)`` over the stacked
+    params with the whole stacked cache in the CARRY: each layer writes its
+    new tokens at ``[layer, ...]`` of the loop's one buffer, so a donated
+    cache is updated in place, with no per-layer slice restacked into a
+    scan output and no whole-cache copy after the loop."""
+    num_layers = jax.tree_util.tree_leaves(stacked)[0].shape[0]
+
+    def step(carry, xs):
+        x, cache = carry
+        lp, layer = xs
+        return layer_fn(x, lp, cache, layer), None
+
+    (x, cache), _ = jax.lax.scan(step, (x, cache),
+                                 (stacked, jnp.arange(num_layers)))
+    return x, cache
 
 
 def _scan_decode(cfg, stacked, cache, x, lengths, *, moe: bool, window):
-    def step(x, xs):
-        lp, cache_layer = xs
-        x, new_cache = _layer_decode(cfg, moe, window, x, lp, cache_layer,
-                                     lengths)
-        return x, new_cache
-
-    x, new_cache = jax.lax.scan(step, x, (stacked, cache))
-    return x, new_cache
+    layer_fn = functools.partial(_layer_decode, cfg, moe, window,
+                                 lengths=lengths)
+    return _scan_carried(layer_fn, stacked, cache, x)
 
 
 def decode_step(params, token, state, cfg: ModelConfig, *,
@@ -304,7 +313,7 @@ def decode_step(params, token, state, cfg: ModelConfig, *,
 # ---------------------------------------------------------------------------
 
 
-def _layer_verify(cfg: ModelConfig, moe: bool, window, x, lp, cache_layer,
+def _layer_verify(cfg: ModelConfig, moe: bool, window, x, lp, cache, layer,
                   lengths):
     """One block over a W-token verify window.  x (B, W, D).
 
@@ -322,12 +331,8 @@ def _layer_verify(cfg: ModelConfig, moe: bool, window, x, lp, cache_layer,
     h = apply_norm(lp["ln1"], x, cfg)
     positions = lengths[:, None] + jnp.arange(W)[None, :]        # (B, W)
     q, k, v = attn.project_qkv(lp["attn"], h, cfg, positions=positions)
-    ck, cv = cache_layer["k"], cache_layer["v"]
-    rows = jnp.arange(B)[:, None]
-    # scatter writes; positions beyond Smax drop (jax scatter OOB default),
-    # matching the dense cache's behavior at the max_len boundary
-    ck = ck.at[rows, positions].set(k.astype(ck.dtype))
-    cv = cv.at[rows, positions].set(v.astype(cv.dtype))
+    cache = attn.stacked_write(cache, layer, positions, {"k": k, "v": v})
+    ck, cv = attn.layer_kv(cache, layer, cfg)
     outs = [attn.decode_attention_ref(q[:, i], ck, cv, lengths + i + 1,
                                       window=window) for i in range(W)]
     out = jnp.stack(outs, axis=1).reshape(B, W,
@@ -343,18 +348,13 @@ def _layer_verify(cfg: ModelConfig, moe: bool, window, x, lp, cache_layer,
             x = x + mo
         else:
             x = x + apply_mlp(lp["mlp"], h2, cfg)
-    return x, {"k": ck, "v": cv}
+    return x, cache
 
 
 def _scan_verify(cfg, stacked, cache, x, lengths, *, moe: bool, window):
-    def step(x, xs):
-        lp, cache_layer = xs
-        x, new_cache = _layer_verify(cfg, moe, window, x, lp, cache_layer,
-                                     lengths)
-        return x, new_cache
-
-    x, new_cache = jax.lax.scan(step, x, (stacked, cache))
-    return x, new_cache
+    layer_fn = functools.partial(_layer_verify, cfg, moe, window,
+                                 lengths=lengths)
+    return _scan_carried(layer_fn, stacked, cache, x)
 
 
 def verify_decode_step(params, tokens, state, cfg: ModelConfig, *,
@@ -432,20 +432,18 @@ def prefill(params, tokens, state, cfg: ModelConfig, *, lengths=None,
                 out = out.reshape(B, S, cfg.num_heads * cfg.head_dim)
                 attn_out = out @ lp["attn"]["wo"] + lp["attn"].get("bo", 0.0)
                 Smax = cache_layer["k"].shape[1]
-                if Smax < S or (window is not None and Smax <= window):
-                    # ring cache: keep only the last `Smax` positions
-                    new_cache = {
-                        "k": attn.ring_fill(k, lengths, Smax).astype(
-                            cache_layer["k"].dtype),
-                        "v": attn.ring_fill(v, lengths, Smax).astype(
-                            cache_layer["v"].dtype),
-                    }
-                else:
-                    pad = [(0, 0), (0, Smax - S), (0, 0), (0, 0)]
-                    new_cache = {
-                        "k": jnp.pad(k, pad).astype(cache_layer["k"].dtype),
-                        "v": jnp.pad(v, pad).astype(cache_layer["v"].dtype),
-                    }
+                ring = Smax < S or (window is not None and Smax <= window)
+
+                def fill(t, like):
+                    if ring:   # ring cache: keep only the last `Smax` positions
+                        t = attn.ring_fill(t, lengths, Smax)
+                    else:
+                        t = jnp.pad(t, [(0, 0), (0, Smax - S), (0, 0), (0, 0)])
+                    # stored heads-flat (B, Smax, K*hd), as init_kv_cache
+                    return t.reshape(like.shape).astype(like.dtype)
+
+                new_cache = {"k": fill(k, cache_layer["k"]),
+                             "v": fill(v, cache_layer["v"])}
             if cfg.parallel_block:
                 x2 = x + attn_out + apply_mlp(lp["mlp"], h, cfg)
             else:

@@ -9,6 +9,10 @@ topology is described inside a fixture: only the test worker that runs
 this file loads the TPU compiler library.
 """
 
+import dataclasses
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -96,11 +100,10 @@ def test_flash_attention_kernel_compiles_with_lengths(one_chip):
     assert "tpu_custom_call" in text
 
 
-def test_full_width_decode_step_fits_one_chip(one_chip):
-    """The fused decode+sample step the scheduler runs every tick, at
-    h2o-danube-1.8b's published widths (bf16, 24 layers), fits one chip."""
-    model = build_model(get_config("h2o-danube-1.8b"))
-    B, max_len = 8, 256
+def _compile_decode_step(one_chip, cfg, B, max_len):
+    """Compile the fused decode+sample step the scheduler runs every tick
+    (``InferenceEngine._decode_sample``) for the described chip."""
+    model = build_model(cfg)
     place = lambda t: jax.tree_util.tree_map(           # noqa: E731
         lambda x: _sds(x.shape, x.dtype, one_chip), t)
     params = place(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
@@ -112,8 +115,49 @@ def test_full_width_decode_step_fits_one_chip(one_chip):
         _sds((B,), jnp.float32, one_chip),
         _sds((B, 2), jnp.uint32, one_chip),
         _sds((B,), jnp.int32, one_chip)).compile()
+    return compiled, state
+
+
+def test_full_width_decode_step_fits_one_chip(one_chip):
+    """The fused decode+sample step the scheduler runs every tick, at
+    h2o-danube-1.8b's published widths (bf16, 24 layers), fits one chip."""
+    compiled, _ = _compile_decode_step(one_chip, get_config("h2o-danube-1.8b"),
+                                       8, 256)
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert mem.argument_size_in_bytes > 3 * 2 ** 30     # the bf16 weights
     assert total < HBM_BYTES, f"{total / 2 ** 30:.2f} GiB"
+
+
+_COPY = re.compile(r"= \w+\[([\d,]*)\]\{([^}]*)\} copy\(")
+
+
+@pytest.mark.parametrize("arch,num_layers,slots", [
+    ("h2o-danube-1.8b", None, 16),      # head_dim 80: not a lane multiple
+    ("yi-9b", 24, 24),                  # head_dim 128
+])
+def test_decode_step_updates_cache_in_place(one_chip, arch, num_layers,
+                                            slots):
+    """The served decode step writes each new token into the donated dense
+    cache in place.  Carried through the layer scan, the stacked cache
+    needs no second copy as a temporary (a scan output restacked per layer
+    held one: 2.23 GB at danube's 16 x 2048, 2.42 GB at yi-9b's 24 x
+    2048), and no copy in HBM moves the whole cache or one layer's slice
+    of it.  Each layer's K and V slices are read from HBM once; the
+    transposes the attention's dots ask of them stay in on-chip memory
+    (memory space S(1))."""
+    cfg = get_config(arch)
+    if num_layers is not None:
+        cfg = dataclasses.replace(cfg, num_layers=num_layers)
+    compiled, state = _compile_decode_step(one_chip, cfg, slots, 2048)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 64 * 2 ** 20, mem.temp_size_in_bytes
+    stacked = state["cache"]["k"].shape
+    whole, layer = math.prod(stacked), math.prod(stacked[1:])
+    assert stacked[:3] == (cfg.num_layers, slots, 2048)
+    for m in _COPY.finditer(compiled.as_text()):
+        n = math.prod(int(d) for d in m.group(1).split(",") if d)
+        on_chip = "S(" in m.group(2)
+        assert n != whole, m.group(0)
+        assert on_chip or n != layer, m.group(0)
