@@ -23,11 +23,13 @@ the last position), in units of the standard deviation of the
 reference's logits there.
 
 Both the widest gap (``max_gap_sd``) and the mean over every compared
-position (``mean_gap_sd``) are read.  With ``control=True`` the same
-prompts are also run through the control (every linear layer in float8,
-see ``reference.Reference``), and the gap of the token or class that the
-control would choose (by the same rule, with the same noise) is read the
-same way, as ``control_max_gap_sd`` and ``control_mean_gap_sd``.
+position (``mean_gap_sd``) are read.  The reference is the class the
+configuration names (``Spec.reference``), passed in by the caller.  With
+``control=True`` the same prompts are also run through its control (the
+precision below the configuration's; for ``references/dense_gqa.py``
+every linear layer in float8), and the gap of the token or class that
+the control would choose (by the same rule, with the same noise) is read
+the same way, as ``control_max_gap_sd`` and ``control_mean_gap_sd``.
 """
 
 from __future__ import annotations
@@ -38,7 +40,6 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 from harness import traffic
-from harness.reference import Reference
 
 GEN_SAMPLE_TOKENS = 1024
 GEN_SAMPLE_MAX_REQUESTS = 12
@@ -147,20 +148,21 @@ def generate_sample(records: List[Dict[str, Any]], reqs, seed: int
     return [r["i"] for r in picked]
 
 
-def check_generate(model: Dict[str, Any], weight_seed: int, reqs,
-                   seed: int, records, pad_to: int,
+def check_generate(reference: type, model: Dict[str, Any],
+                   weight_seed: int, reqs, seed: int, records, pad_to: int,
                    sampling: Optional[Dict[str, float]] = None, *,
                    control: bool = False) -> Dict[str, Any]:
-    """``reqs`` is the run's schedule, ``records`` the load generator's
-    records, ``sampling`` the mix's (None: greedy); rows are padded to
-    ``pad_to`` positions (one compile)."""
+    """``reference`` is the configuration's reference class, ``reqs``
+    the run's schedule, ``records`` the load generator's records,
+    ``sampling`` the mix's (None: greedy); rows are padded to ``pad_to``
+    positions (one compile)."""
     by_i = {r["i"]: r for r in records}
     picked = generate_sample(records, reqs, seed)
     if not picked:
         return {"max_gap_sd": None, "tokens_compared": 0}
-    refs = [Reference(model, weight_seed)]
+    refs = [reference(model, weight_seed)]
     if control:
-        refs.append(Reference(model, weight_seed, control=True))
+        refs.append(reference(model, weight_seed, control=True))
     rows, lengths, positions, served = [], [], [], []
     for i in picked:
         prompt = traffic.prompt_tokens(seed, i, reqs[i]["prompt_len"],
@@ -203,11 +205,12 @@ def check_generate(model: Dict[str, Any], weight_seed: int, reqs,
     return out
 
 
-def check_infer(model: Dict[str, Any], members: int, weight_seed: int,
-                reqs, row_tokens: int, seed: int, records,
-                num_classes: int, *, control: bool = False
+def check_infer(reference: type, model: Dict[str, Any], members: int,
+                weight_seed: int, reqs, row_tokens: int, seed: int,
+                records, num_classes: int, *, control: bool = False
                 ) -> Dict[str, Any]:
-    """Member m's weights come from ``weight_seed + m``."""
+    """``reference`` is the configuration's reference class; member m's
+    weights come from ``weight_seed + m``."""
     rng = np.random.default_rng([seed, 0xC4EC])
     answered = [r for r in records
                 if r.get("status") == 200 and r.get("classes")]
@@ -228,9 +231,9 @@ def check_infer(model: Dict[str, Any], members: int, weight_seed: int,
     S = toks.shape[1]
     gaps = {"program": [], "control": []}
     for m in range(members):
-        refs = [Reference(model, weight_seed + m)]
+        refs = [reference(model, weight_seed + m)]
         if control:
-            refs.append(Reference(model, weight_seed + m, control=True))
+            refs.append(reference(model, weight_seed + m, control=True))
         served = np.asarray([int(c[m].split("_")[-1]) for _, c in rows])
         starts = list(range(0, len(rows), INFER_BLOCK_ROWS))
         blocks = [(toks[lo:lo + INFER_BLOCK_ROWS],

@@ -3,11 +3,14 @@
 ``BENCHMARK.json`` at the checkout's root names each cell's
 configuration (``bench/configs/<config>.json``), its traffic
 (``bench/traffic/<traffic>.json``) and the metrics it reports; a
-per-layer metric's reader is ``bench/metrics/<metric>.py``.  Adding a
-cell, a configuration, a mix or a metric adds files and entries only."""
+per-layer metric's reader is ``bench/metrics/<metric>.py``; the plain
+reference a configuration names (``"reference"``) is
+``bench/references/<reference>.py``.  Adding a cell, a configuration, a
+mix, a metric or a reference adds files and entries only."""
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 from pathlib import Path
@@ -32,8 +35,13 @@ class Spec:
                        f"{[w['name'] for w in self.doc['workloads']]}")
 
     def config(self, name: str) -> Dict[str, Any]:
-        return json.loads((self.bench / "configs" / f"{name}.json")
-                          .read_text())
+        path = self.bench / "configs" / f"{name}.json"
+        cfg = json.loads(path.read_text())
+        if "reference" not in cfg:
+            raise KeyError(f"{path}: no \"reference\" key; a configuration "
+                           f"names the file under bench/references/ that "
+                           f"holds its plain reference")
+        return cfg
 
     def mix(self, name: str) -> Dict[str, Any]:
         return traffic.load_mix(self.bench / "traffic", name)
@@ -56,9 +64,28 @@ class Spec:
 
     def reader(self, metric: str) -> Callable[[Dict[str, Any]], Any]:
         path = self.bench / "metrics" / f"{metric}.py"
-        mod_spec = importlib.util.spec_from_file_location(
-            "bench_metric_" + metric.replace(".", "_").replace("-", "_"),
-            path)
-        mod = importlib.util.module_from_spec(mod_spec)
-        mod_spec.loader.exec_module(mod)
-        return mod.read
+        return _load(path, "bench_metric_").read
+
+    def reference(self, name: str) -> type:
+        """The ``Reference`` class of ``bench/references/<name>.py``.
+
+        Its contract: ``Reference(model, seed, control=False)`` makes the
+        weights from ``seed`` as the program does for the configuration's
+        ``model``; ``.logits(blocks, positions, cols=None)`` takes a list
+        of (tokens (B, S), lengths (B,)) and, per block and row, the
+        positions to read, and returns per block and row a float32
+        array (positions, vocab), the first ``cols`` columns when set.
+        ``control=True`` computes in the precision below the
+        configuration's."""
+        path = self.bench / "references" / f"{name}.py"
+        return _load(path, "bench_reference_").Reference
+
+
+@functools.lru_cache(maxsize=None)
+def _load(path: Path, prefix: str):
+    """The module at ``path``, loaded once per process."""
+    name = path.stem.replace(".", "_").replace("-", "_")
+    mod_spec = importlib.util.spec_from_file_location(prefix + name, path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    return mod

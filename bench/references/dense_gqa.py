@@ -18,7 +18,12 @@ weights are on the device at a time.
 ``control=True`` computes every linear layer in the precision below the
 configuration's bf16: both operands of each weight matmul (and of the
 head) rounded to float8_e4m3 with a per-tensor scale, accumulated in
-float32 (see ``fp8``)."""
+float32 (see ``fp8``).
+
+A configuration names this file with ``"reference": "dense_gqa"``.  A
+reference for another architecture is a file of its own beside it that
+may reuse these helpers (``from references.dense_gqa import fp8, _mat,
+_rms, _rope, attention``)."""
 
 from __future__ import annotations
 
@@ -164,15 +169,13 @@ def _rope(x, pos, theta):
     return jnp.concatenate([a * cos - b * sin, a * sin + b * cos], -1)
 
 
-@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8, 9))
-def _block(w, x, lengths, H, K, hd, theta, window, eps, control):
-    B, S, d = x.shape
-    lo = fp8 if control else (lambda t: t)     # left operand of a matmul
-    pos = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
-    h = lo(_rms(x, eps))
-    q = _rope((h @ w["wq"]).reshape(B, S, H, hd), pos, theta)
-    k = _rope((h @ w["wk"]).reshape(B, S, K, hd), pos, theta)
-    v = (h @ w["wv"]).reshape(B, S, K, hd)
+def attention(q, k, v, lengths, window):
+    """Causal softmax attention over right-padded rows, with an optional
+    sliding window: q (B, S, H, hd), k and v (B, S, K, hd), query head j
+    reading kv head j // (H // K); ``lengths`` (B,) masks the padding.
+    Returns (B, S, H * hd)."""
+    B, S, H, hd = q.shape
+    K = k.shape[2]
     k = jnp.repeat(k, H // K, axis=2)          # query head j reads kv j//G
     v = jnp.repeat(v, H // K, axis=2)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(hd)
@@ -183,7 +186,19 @@ def _block(w, x, lengths, H, K, hd, theta, window, eps, control):
     ok = ok[None, None] & (ki < lengths[:, None, None, None])
     s = jnp.where(ok, s, NEG)
     p = jax.nn.softmax(s, axis=-1)
-    a = jnp.einsum("bhqk,bkhd->bqhd", p, v).reshape(B, S, H * hd)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v).reshape(B, S, H * hd)
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _block(w, x, lengths, H, K, hd, theta, window, eps, control):
+    B, S, d = x.shape
+    lo = fp8 if control else (lambda t: t)     # left operand of a matmul
+    pos = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
+    h = lo(_rms(x, eps))
+    q = _rope((h @ w["wq"]).reshape(B, S, H, hd), pos, theta)
+    k = _rope((h @ w["wk"]).reshape(B, S, K, hd), pos, theta)
+    v = (h @ w["wv"]).reshape(B, S, K, hd)
+    a = attention(q, k, v, lengths, window)
     x = x + lo(a) @ w["wo"]
     h = lo(_rms(x, eps))
     return x + lo(jax.nn.silu(h @ w["w_gate"]) * (h @ w["w_up"])) @ w["w_down"]

@@ -9,7 +9,8 @@ warms only the shapes of this cell's traffic, serves it with
 ``FlexServeServer`` and drives it over HTTP from a load generator in a
 child process that never imports JAX.  After the window it reads device
 memory, stops the server, frees the program's arrays and compares a
-sample of what was served with the plain float32 reference.
+sample of what was served with the plain float32 reference that the
+configuration names.
 
 The last line of standard output is the result:
 ``{"correct", "attempted", "failed", "metrics", "device"[, "breakdown"],
@@ -76,23 +77,50 @@ def serve_arch(cfg: Dict[str, Any]) -> str:
     """Name of the program's ModelConfig that serves ``cfg``: the
     program's own arch, or a copy registered under the configuration's
     name with ``overrides`` applied.  Every number of the file's
-    ``model`` must match what the program will run."""
+    ``model`` must match what the program will run.  Both may nest: a
+    dict given for a field that holds a sub-config (``"moe": {...}``) is
+    compared, or replaced, field by field, and only the fields it gives."""
     from repro.configs import get_config, register
     name = cfg["program_arch"]
     if cfg.get("overrides"):
         try:
-            mc = get_config(cfg["name"])
+            get_config(cfg["name"])
         except KeyError:
-            mc = register(dataclasses.replace(
-                get_config(name), name=cfg["name"], **cfg["overrides"]))
+            base = get_config(name)
+            register(dataclasses.replace(
+                base, name=cfg["name"], **_replaced(base, cfg["overrides"])))
         name = cfg["name"]
-    mc = get_config(name)
-    for k, v in cfg["model"].items():
-        got = getattr(mc, k)
-        if got != v:
-            raise ValueError(f"config {cfg['name']}: {k} is {v} in the "
-                             f"file but {got} in the program")
+    bad = [f"{path} is {want} in the file but {got} in the program"
+           for path, want, got in _mismatches(cfg["model"],
+                                              get_config(name))]
+    if bad:
+        raise ValueError(f"config {cfg['name']}: " + "; ".join(bad))
     return name
+
+
+def _replaced(obj, overrides: Dict[str, Any]) -> Dict[str, Any]:
+    """``overrides`` as ``dataclasses.replace`` arguments for ``obj``: a
+    dict given for a field that holds a dataclass replaces that
+    sub-config's fields and keeps the rest."""
+    out = {}
+    for k, v in overrides.items():
+        cur = getattr(obj, k)
+        if isinstance(v, dict) and dataclasses.is_dataclass(cur):
+            v = dataclasses.replace(cur, **_replaced(cur, v))
+        out[k] = v
+    return out
+
+
+def _mismatches(want: Dict[str, Any], obj, prefix: str = ""):
+    """(dotted path, file's value, program's value) of every key of
+    ``want`` that ``obj`` does not hold; a tuple equals the list JSON
+    gives for it."""
+    for k, v in want.items():
+        got = getattr(obj, k, "absent")
+        if isinstance(v, dict) and dataclasses.is_dataclass(got):
+            yield from _mismatches(v, got, f"{prefix}{k}.")
+        elif (list(got) if isinstance(got, tuple) else got) != v:
+            yield f"{prefix}{k}", v, got
 
 
 class Session:
@@ -258,15 +286,18 @@ class Session:
 
     def compare(self, seed: int, seconds: float, records, *,
                 control: bool = False) -> Dict[str, Any]:
+        """What was served against the reference the configuration
+        names."""
         reqs = traffic.schedule(self.mix, seed, seconds)
         model = self.cfg["model"]
+        ref = self.spec.reference(self.cfg["reference"])
         if self.mix["plane"] == "infer":
             return check.check_infer(
-                model, self.members, self.wseed, reqs,
+                ref, model, self.members, self.wseed, reqs,
                 self.mix["row_tokens"], seed, records,
                 self.cfg["serve"]["num_classes"], control=control)
-        return check.check_generate(model, self.wseed, reqs, seed, records,
-                                    self.cfg["serve"]["max_len"],
+        return check.check_generate(ref, model, self.wseed, reqs, seed,
+                                    records, self.cfg["serve"]["max_len"],
                                     self.mix.get("sampling"),
                                     control=control)
 

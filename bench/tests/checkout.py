@@ -20,7 +20,7 @@ TINY_MODEL = {"num_layers": 2, "d_model": 128, "num_heads": 4,
 
 TINY_CONFIG = {
     "name": "tiny-gqa", "source": "a tiny dense-GQA model for CPU tests",
-    "program_arch": "yi-9b",
+    "program_arch": "yi-9b", "reference": "dense_gqa",
     "overrides": {k: TINY_MODEL[k] for k in
                   ("num_layers", "d_model", "num_heads", "num_kv_heads",
                    "head_dim", "d_ff", "vocab_size", "dtype")},

@@ -1,6 +1,10 @@
-"""CPU rehearsal of a whole run: a configuration and mixes dropped in
-beside the shipped ones are found by name, with no edit to the harness,
-and the same --seed gives the same schedule."""
+"""CPU rehearsal of a whole run: a configuration, mixes and a reference
+dropped in beside the shipped ones are found by name, with no edit to the
+harness, and the same --seed gives the same schedule.  A configuration's
+``model`` and ``overrides`` may nest."""
+
+import dataclasses
+import json
 
 import pytest
 
@@ -87,3 +91,102 @@ def test_same_seed_same_schedule(tiny):
     greedy = traffic.load_mix(tiny / "bench" / "traffic", "tiny_batch")
     assert "temperature" not in traffic.payload(
         greedy, traffic.schedule(greedy, 1, 20.0)[0], 1, 512)
+
+
+def test_cell_compares_with_the_reference_its_config_names(tiny):
+    out = checkout.drive(tiny, (
+        "names = []\n"
+        "orig = run.Spec.reference\n"
+        "def reference(self, name):\n"
+        "    names.append(name)\n"
+        "    return orig(self, name)\n"
+        "run.Spec.reference = reference\n"
+        "out = run.run('tiny.chat', 2**32 + 17, 4.0, False, "
+        "require_tpu=False, bench_dir=run.Path('bench'))\n"
+        "out['names'] = names\n"
+        "print(json.dumps(out))\n"))
+    assert out["names"] == ["dense_gqa"]
+    assert out["correct"] is True, out["checks"]
+
+
+# a reference that only a test's checkout holds: the dense one with its
+# logits rolled by one along the vocabulary
+ROLLED_REFERENCE = """
+import numpy as np
+from references.dense_gqa import Reference as Dense
+
+
+class Reference(Dense):
+    def logits(self, blocks, positions, cols=None):
+        return [[np.roll(r, 1, axis=-1) for r in blk]
+                for blk in super().logits(blocks, positions, cols)]
+"""
+
+
+def test_added_reference_is_reached_by_name(tmp_path):
+    root = checkout.make(tmp_path)
+    (root / "bench" / "references" / "rolled_gqa.py").write_text(
+        ROLLED_REFERENCE)
+    cfg = dict(checkout.TINY_CONFIG, name="tiny-rolled",
+               reference="rolled_gqa")
+    (root / "bench" / "configs" / "tiny-rolled.json").write_text(
+        json.dumps(cfg))
+    doc = json.loads((root / "BENCHMARK.json").read_text())
+    doc["configs"].append({"name": "tiny-rolled", "source": "test",
+                           "file": "bench/configs/tiny-rolled.json",
+                           "reduced": [], "why": "CPU rehearsal"})
+    doc["workloads"].append({"name": "tiny.rolled", "config": "tiny-rolled",
+                             "traffic": "tiny_chat", "chips": 1,
+                             "why": "CPU rehearsal"})
+    (root / "BENCHMARK.json").write_text(json.dumps(doc))
+    out = checkout.drive(root, (
+        "out = run.run('tiny.rolled', 2**33 + 3, 4.0, False, "
+        "require_tpu=False, bench_dir=run.Path('bench'))\n"
+        "print(json.dumps(out))\n"))
+    assert out["correct"] is False
+    c = out["checks"]["max_gap_sd"]
+    assert c["value"] > c["limit"]
+
+
+def test_config_without_reference_is_refused(tmp_path):
+    from harness.spec import Spec
+    (tmp_path / "bench" / "configs").mkdir(parents=True)
+    (tmp_path / "BENCHMARK.json").write_text("{}")
+    cfg = {k: v for k, v in checkout.TINY_CONFIG.items()
+           if k != "reference"}
+    (tmp_path / "bench" / "configs" / "tiny-gqa.json").write_text(
+        json.dumps(cfg))
+    with pytest.raises(KeyError, match="tiny-gqa.json.*reference"):
+        Spec(tmp_path / "bench").config("tiny-gqa")
+
+
+def test_serve_arch_nests_model_and_overrides():
+    import run
+    from repro.configs import get_config
+    prog = get_config("qwen3-moe-235b-a22b")
+    moe = dataclasses.asdict(prog.moe)
+    # the program's own arch: a nested dict compared field by field
+    own = {"name": "qwen3-moe-235b-a22b",
+           "program_arch": "qwen3-moe-235b-a22b",
+           "model": {"num_layers": 94, "moe": moe}}
+    assert run.serve_arch(own) == "qwen3-moe-235b-a22b"
+    # a nested override replaces the fields it gives and keeps the rest;
+    # the file's model names only some of the sub-config's fields
+    cut = {"name": "qwen3-moe-16e", "program_arch": "qwen3-moe-235b-a22b",
+           "overrides": {"num_layers": 2, "moe": {"num_experts": 16}},
+           "model": {"num_layers": 2, "d_model": 4096,
+                     "moe": {"num_experts": 16, "top_k": 8}}}
+    assert run.serve_arch(cut) == "qwen3-moe-16e"
+    got = get_config("qwen3-moe-16e")
+    assert got.moe == dataclasses.replace(prog.moe, num_experts=16)
+    assert got.num_layers == 2 and prog.num_layers == 94
+    assert prog.moe.num_experts == 128
+    wrong = dict(cut, model={"num_layers": 2,
+                             "moe": dict(moe, num_experts=16, top_k=4)})
+    with pytest.raises(ValueError, match=r"moe\.top_k is 4 in the file "
+                                         r"but 8 in the program"):
+        run.serve_arch(wrong)
+    with pytest.raises(ValueError, match=r"moe\.num_experts is 64 .* 128"):
+        run.serve_arch(dict(own, model={"moe": {"num_experts": 64}}))
+    with pytest.raises(ValueError, match=r"moe\.num_experts_x is 1"):
+        run.serve_arch(dict(own, model={"moe": {"num_experts_x": 1}}))

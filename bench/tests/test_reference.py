@@ -2,16 +2,19 @@
 size on the CPU: weights made from the seed must equal the program's
 bit for bit, and prefill plus cached decode through the engine must give
 the reference's logits (float32: to rounding; bfloat16: within the
-rounding of bf16 activations)."""
+rounding of bf16 activations).  The dense reference's own logits are
+pinned bit for bit to those it gave before it moved to
+``references/dense_gqa.py``."""
 
 import dataclasses
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from harness.reference import Reference, fp8
+from references.dense_gqa import Reference, fp8
 from repro.configs import get_config
 from repro.core import InferenceEngine
 from repro.models.build import build_model
@@ -73,3 +76,28 @@ def test_control_weights_are_coarser():
     q = fp8(w)
     rel = float(jnp.abs(q - w).max() / jnp.abs(w).max())
     assert 1e-3 < rel < 0.1
+
+
+PIN = Path(__file__).resolve().parent / "data" / "dense_gqa_pin.npz"
+
+
+@pytest.mark.parametrize("case,dtype,window,control,cols", [
+    ("bf16_window", "bfloat16", 16, False, None),
+    ("bf16_window_control", "bfloat16", 16, True, None),
+    ("f32_cols", "float32", None, False, 64),
+])
+def test_dense_reference_logits_are_pinned(case, dtype, window, control,
+                                           cols):
+    # the pinned logits were written by the reference as it stood at
+    # bench/harness/reference.py, on these tokens, seed and positions
+    pin = np.load(PIN)
+    m = dict(TINY, dtype=dtype, sliding_window=window, rope_theta=1e4,
+             norm_eps=1e-5)
+    blocks = [(pin["t1"], np.asarray([37], np.int32)),
+              (pin["t2"], np.asarray([24, 17], np.int32))]
+    pos = [[[5, 20, 36]], [[0, 11, 23], [3, 9, 16]]]
+    got = Reference(m, 2 ** 31 - 99, control=control).logits(blocks, pos,
+                                                              cols=cols)
+    got = np.stack([r for blk in got for r in blk])
+    assert got.dtype == np.float32 and got.shape == pin[case].shape
+    assert np.array_equal(got, pin[case])
