@@ -6,6 +6,7 @@ from repro.configs.base import (
     ModelConfig,
     SSMConfig,
     VLMConfig,
+    YarnConfig,
     get_config,
     list_configs,
     reduce_for_smoke,

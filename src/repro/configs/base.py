@@ -31,6 +31,32 @@ class MoEConfig:
     d_ff_dense: int = 0             # d_ff of those dense layers
     router_aux_weight: float = 1e-3
     norm_topk_prob: bool = True
+    scoring: str = "softmax"        # softmax | sigmoid (deepseek-v3, with a
+                                    # selection-only bias per expert)
+    n_group: int = 1                # routing groups of experts
+    topk_group: int = 1             # groups a token may route into
+    routed_scaling: float = 1.0     # factor on the routed experts' weights
+    experts_held: Optional[int] = None  # experts [0, n) held here (None:
+                                    # all); routing still spans every expert
+
+    def __post_init__(self):
+        E, G = self.num_experts, self.n_group
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown MoE scoring {self.scoring!r}")
+        if E % G or not 1 <= self.topk_group <= G:
+            raise ValueError(f"{E} experts in {G} groups, {self.topk_group} "
+                             f"kept: groups must divide the experts and "
+                             f"1 <= topk_group <= n_group")
+        if self.top_k > self.topk_group * (E // G):
+            raise ValueError(f"top_k {self.top_k} exceeds the experts of "
+                             f"{self.topk_group} kept groups")
+        if not 1 <= self.held <= E:
+            raise ValueError(f"experts_held {self.experts_held} not in "
+                             f"[1, {E}]")
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.num_experts
 
 
 @dataclass(frozen=True)
@@ -42,6 +68,22 @@ class MLAConfig:
     rope_head_dim: int = 64
     nope_head_dim: int = 128
     v_head_dim: int = 128
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling (deepseek-v3's ``rope_scaling``), applied to the
+    rotary part of MLA: frequencies blended between extrapolation and
+    interpolation by ``factor`` over the dims between the beta_fast and
+    beta_slow rotation counts, and the softmax scale multiplied by
+    mscale(mscale_all_dim) squared."""
+
+    factor: float = 40.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 1.0
 
 
 @dataclass(frozen=True)
@@ -106,6 +148,7 @@ class ModelConfig:
     attn_kind: str = "gqa"          # gqa | mla | none
     sliding_window: Optional[int] = None   # native SWA (h2o-danube)
     rope_theta: float = 10000.0
+    rope_scaling: Optional[YarnConfig] = None   # MLA only
     use_bias: bool = False
     use_qk_norm: bool = False       # qwen3
     norm_kind: str = "rmsnorm"      # rmsnorm | layernorm
@@ -131,6 +174,9 @@ class ModelConfig:
     def __post_init__(self):
         if self.head_dim == 0:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
+        if self.rope_scaling is not None and self.attn_kind != "mla":
+            raise ValueError(f"{self.name}: rope_scaling is applied by MLA "
+                             f"only, not by attn_kind={self.attn_kind}")
         if self.num_heads % max(self.num_kv_heads, 1) != 0:
             raise ValueError(
                 f"{self.name}: num_heads={self.num_heads} not divisible by "
@@ -182,7 +228,7 @@ class ModelConfig:
             per_layer = attn_params() + mlp_params(ff)
         elif self.family == "moe":
             m = self.moe
-            n_routed = m.top_k if active_only else m.num_experts
+            n_routed = m.top_k if active_only else m.held
             moe_mlp = (n_routed + m.num_shared_experts) * mlp_params(m.d_ff_expert)
             router = d * m.num_experts
             dense_layers = m.first_k_dense
@@ -246,13 +292,24 @@ def reduce_for_smoke(cfg: ModelConfig) -> ModelConfig:
         dtype="float32",
     )
     if cfg.moe:
+        m = cfg.moe
+        # grouped routing keeps 8 experts in 4 groups of 2, 2 groups kept,
+        # so the group choice still excludes experts; n_group divides the
+        # experts and topk_group <= n_group
+        grouped = m.n_group > 1
+        num_experts = min(m.num_experts, 8 if grouped else 4)
+        n_group = min(m.n_group, 4)
         changes["moe"] = dataclasses.replace(
-            cfg.moe,
-            num_experts=min(cfg.moe.num_experts, 4),
-            top_k=min(cfg.moe.top_k, 2),
-            d_ff_expert=min(cfg.moe.d_ff_expert, 256),
-            first_k_dense=min(cfg.moe.first_k_dense, 1),
-            d_ff_dense=min(cfg.moe.d_ff_dense or 512, 512),
+            m,
+            num_experts=num_experts,
+            top_k=min(m.top_k, 2),
+            d_ff_expert=min(m.d_ff_expert, 256),
+            first_k_dense=min(m.first_k_dense, 1),
+            d_ff_dense=min(m.d_ff_dense or 512, 512),
+            n_group=n_group,
+            topk_group=min(m.topk_group, max(1, n_group // 2)),
+            experts_held=(None if m.experts_held is None
+                          else min(m.experts_held, num_experts // 2)),
         )
     if cfg.mla:
         changes["mla"] = MLAConfig(

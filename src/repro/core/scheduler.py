@@ -229,6 +229,12 @@ class ContinuousBatchingScheduler:
         self.max_prefill_batch = (min(cap, max_prefill_batch)
                                   if max_prefill_batch else cap)
         self.state = engine.new_state(num_slots)
+        # an expert model's steps return their (held, routed) expert rows
+        # in the state (``moe_rows``); fetched with the step's tokens and
+        # summed here.  None for models without experts.
+        self.moe_rows = (np.zeros((2,), np.int64)
+                         if isinstance(self.state, dict)
+                         and "moe_rows" in self.state else None)
         self.slots: List[Optional[Request]] = [None] * num_slots
         self.queue: Deque[Request] = collections.deque()       # interactive
         self.bulk_queue: Deque[Request] = collections.deque()
@@ -527,6 +533,12 @@ class ContinuousBatchingScheduler:
                 draws = np.asarray(draws_dev)        # blocks: device sync
                 counts = np.asarray(counts_dev)
                 transfer = draws.nbytes + counts.nbytes
+            elif self.device_sampling and self.moe_rows is not None:
+                # one fetch: the rows are ready with the tokens
+                tokens, rows = jax.device_get((tok_dev,
+                                               self.state["moe_rows"]))
+                self.moe_rows += rows
+                transfer = tokens.nbytes + rows.nbytes
             elif self.device_sampling:
                 tokens = np.asarray(tok_dev)         # blocks: device sync
                 transfer = tokens.nbytes
@@ -536,6 +548,8 @@ class ContinuousBatchingScheduler:
             else:
                 host = np.asarray(logits)            # (num_slots, V)
                 transfer = host.nbytes
+            if self.moe_rows is not None and tokens is None:
+                self.moe_rows += np.asarray(self.state["moe_rows"])
             device_s = time.perf_counter() - t_dev
         with phase("emit", tick=tick):
             self.steps += 1
@@ -810,14 +824,21 @@ class ContinuousBatchingScheduler:
                 samp["top_p"][i] = p.top_p
                 samp["key"][i] = req.base_key
                 ctr[i] = len(req.output)
-            firsts = np.asarray(self.engine.sample(
+            firsts = self.engine.sample(
                 logits, {k: jnp.asarray(v) for k, v in samp.items()},
-                jnp.asarray(ctr)))
+                jnp.asarray(ctr))
+            if self.moe_rows is not None:
+                firsts, rows = jax.device_get((firsts,
+                                               group_state["moe_rows"]))
+                self.moe_rows += rows
+            firsts = np.asarray(firsts)
             self.prefill_transfer_bytes += firsts.nbytes
         else:
             host = np.asarray(logits)                         # (B, V)
             self.prefill_transfer_bytes += host.nbytes
             firsts = [reqs[i].sampler.sample(host[i]) for i in range(n)]
+            if self.moe_rows is not None:
+                self.moe_rows += np.asarray(group_state["moe_rows"])
         prefill_s = time.perf_counter() - t0
         now = time.perf_counter()
         src_rows = np.zeros((self.num_slots,), np.int32)
@@ -979,9 +1000,14 @@ class ContinuousBatchingScheduler:
                 samp["top_p"][i] = p.top_p
                 samp["key"][i] = req.base_key
                 ctr[i] = len(req.output)
-            firsts = np.asarray(self.engine.sample(
+            firsts = self.engine.sample(
                 logits, {k: jnp.asarray(v) for k, v in samp.items()},
-                jnp.asarray(ctr)))
+                jnp.asarray(ctr))
+            if self.moe_rows is not None:
+                firsts, rows = jax.device_get((firsts,
+                                               group_state["moe_rows"]))
+                self.moe_rows += rows
+            firsts = np.asarray(firsts)
             self.prefill_transfer_bytes += firsts.nbytes
         else:
             host = np.asarray(logits)                         # (B, V)
@@ -1683,6 +1709,11 @@ class SchedulerService:
                 "prefill_ms_hist": h["prefill_ms"].snapshot(),
                 "transfer_bytes_hist": h["tick_transfer_bytes"].snapshot(),
             }
+            if s.moe_rows is not None:
+                # (token, expert) rows routed by prefill and decode steps,
+                # and those that fell to the experts held here
+                decode["moe_held_rows_total"] = int(s.moe_rows[0])
+                decode["moe_routed_rows_total"] = int(s.moe_rows[1])
             return {
                 "decode": decode,
                 "pager": s.pager_stats() or dict(ZERO_PAGER_STATS),

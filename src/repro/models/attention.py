@@ -22,7 +22,8 @@ import jax.numpy as jnp
 from repro import opt
 from repro.configs.base import ModelConfig
 from repro.models.layers import (
-    apply_rope, compute_dtype, dense_init, rms_norm_simple)
+    apply_rope, compute_dtype, dense_init, rms_norm_simple, yarn_freqs,
+    yarn_mscale)
 from repro.sharding import shard
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
@@ -380,6 +381,29 @@ def init_mla(key, cfg: ModelConfig):
     }
 
 
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """1/sqrt(nope + rope), times mscale(mscale_all_dim)^2 under YaRN."""
+    m = cfg.mla
+    scale = (m.nope_head_dim + m.rope_head_dim) ** -0.5
+    ys = cfg.rope_scaling
+    if ys is not None and ys.mscale_all_dim:
+        scale *= yarn_mscale(ys.factor, ys.mscale_all_dim) ** 2
+    return scale
+
+
+def _mla_rope(x, positions, cfg):
+    """Rotary positions on MLA's rope dims, YaRN-scaled where configured:
+    cos and sin carry mscale(mscale) / mscale(mscale_all_dim)."""
+    ys = cfg.rope_scaling
+    if ys is None:
+        return apply_rope(x, positions, cfg.rope_theta)
+    out = apply_rope(x, positions, cfg.rope_theta,
+                     freqs=yarn_freqs(x.shape[-1], cfg.rope_theta, ys))
+    ratio = (yarn_mscale(ys.factor, ys.mscale)
+             / yarn_mscale(ys.factor, ys.mscale_all_dim))
+    return out if ratio == 1.0 else (out * ratio).astype(out.dtype)
+
+
 def _mla_q(p, x, cfg, positions):
     m = cfg.mla
     B, S, _ = x.shape
@@ -387,7 +411,7 @@ def _mla_q(p, x, cfg, positions):
     q_lat = rms_norm_simple(x @ p["q_a"], p["q_a_scale"])
     q = (q_lat @ p["q_b"]).reshape(B, S, H, m.rope_head_dim + m.nope_head_dim)
     q_nope, q_rope = jnp.split(q, [m.nope_head_dim], axis=-1)
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = _mla_rope(q_rope, positions, cfg)
     return q_nope, q_rope
 
 
@@ -396,13 +420,50 @@ def _mla_ckv(p, x, cfg, positions):
     kv = x @ p["kv_a"]                                   # (B,S,kvr+rope)
     c_kv, k_rope = jnp.split(kv, [m.kv_lora_rank], axis=-1)
     c_kv = rms_norm_simple(c_kv, p["kv_a_scale"])
-    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
+    k_rope = _mla_rope(k_rope[:, :, None, :], positions, cfg)[:, :, 0]
     return c_kv, k_rope
 
 
-def mla_attention_block(p, x, cfg: ModelConfig, *, positions=None,
-                        kv_lengths=None):
-    """Full-sequence MLA (train/prefill): materializes per-head k,v."""
+# prefill attention runs in blocks of this many queries, so its scores are
+# (B, H, MLA_Q_BLOCK, S) and not (B, H, S, S): 128 heads at S = 2048 would
+# otherwise hold 2 GiB of float32 scores a layer, and as much again in
+# probabilities
+MLA_Q_BLOCK = 256
+
+
+def _mla_attend_block(q_nope, q_rope, k_nope, k_rope, v, kv_lengths, scale,
+                      q_offset):
+    """Causal attention of a block of queries starting at ``q_offset``
+    against every key: q (B, bq, H, .), k_nope/v (B, S, H, .), k_rope
+    (B, S, r).  Returns (B, bq, H, v) float32."""
+    bq, S = q_nope.shape[1], k_nope.shape[1]
+    if opt.enabled("attn_dtype"):
+        scores = (jnp.einsum("bshn,bthn->bhst", q_nope, k_nope,
+                             preferred_element_type=jnp.float32)
+                  + jnp.einsum("bshr,btr->bhst", q_rope, k_rope,
+                               preferred_element_type=jnp.float32)) * scale
+    else:
+        f32 = jnp.float32
+        scores = (jnp.einsum("bshn,bthn->bhst", q_nope.astype(f32),
+                             k_nope.astype(f32))
+                  + jnp.einsum("bshr,btr->bhst", q_rope.astype(f32),
+                               k_rope.astype(f32))) * scale
+    mask = make_mask(bq, S, causal=True, q_offset=q_offset,
+                     kv_lengths=kv_lengths)
+    scores = jnp.where(mask, scores, NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1)
+    if opt.enabled("attn_dtype"):
+        return jnp.einsum("bhst,bthv->bshv", probs.astype(v.dtype), v,
+                          preferred_element_type=jnp.float32)
+    return jnp.einsum("bhst,bthv->bshv", probs, v.astype(jnp.float32))
+
+
+def mla_prefill_block(p, x, cfg: ModelConfig, *, positions=None,
+                      kv_lengths=None):
+    """Full-sequence MLA (train/prefill): materializes per-head k, v and
+    attends in blocks of ``MLA_Q_BLOCK`` queries.  Returns (out (B,S,D),
+    c_kv (B,S,kvr), k_rope (B,S,rope)): the latent the decode cache
+    holds, computed once."""
     m = cfg.mla
     B, S, _ = x.shape
     H = cfg.num_heads
@@ -412,26 +473,59 @@ def mla_attention_block(p, x, cfg: ModelConfig, *, positions=None,
     c_kv, k_rope = _mla_ckv(p, x, cfg, positions)
     kvb = (c_kv @ p["kv_b"]).reshape(B, S, H, m.nope_head_dim + m.v_head_dim)
     k_nope, v = jnp.split(kvb, [m.nope_head_dim], axis=-1)
-    scale = 1.0 / jnp.sqrt(m.nope_head_dim + m.rope_head_dim)
-    scores = (jnp.einsum("bshn,bthn->bhst", q_nope.astype(jnp.float32),
-                         k_nope.astype(jnp.float32))
-              + jnp.einsum("bshr,btr->bhst", q_rope.astype(jnp.float32),
-                           k_rope.astype(jnp.float32))) * scale
-    mask = make_mask(S, S, causal=True, kv_lengths=kv_lengths)
-    scores = jnp.where(mask, scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bhst,bthv->bshv", probs, v.astype(jnp.float32))
+    scale = mla_softmax_scale(cfg)
+    with jax.named_scope("mla.attend"):
+        if S <= MLA_Q_BLOCK or S % MLA_Q_BLOCK:
+            out = _mla_attend_block(q_nope, q_rope, k_nope, k_rope, v,
+                                    kv_lengths, scale, 0)
+        else:
+            nb = S // MLA_Q_BLOCK
+
+            def blocks(t):                  # (B, S, ...) -> (nb, B, bq, ...)
+                t = t.reshape(B, nb, MLA_Q_BLOCK, *t.shape[2:])
+                return jnp.moveaxis(t, 1, 0)
+
+            out = jax.lax.map(
+                lambda a: _mla_attend_block(a[0], a[1], k_nope, k_rope, v,
+                                            kv_lengths, scale, a[2]),
+                (blocks(q_nope), blocks(q_rope),
+                 jnp.arange(nb) * MLA_Q_BLOCK))
+            out = jnp.moveaxis(out, 0, 1)   # (B, nb, bq, H, v)
     out = out.reshape(B, S, H * m.v_head_dim).astype(x.dtype)
-    return out @ p["wo"]
+    return out @ p["wo"], c_kv, k_rope
+
+
+def mla_attention_block(p, x, cfg: ModelConfig, *, positions=None,
+                        kv_lengths=None):
+    """Full-sequence MLA (train/prefill).  Returns (B,S,D)."""
+    return mla_prefill_block(p, x, cfg, positions=positions,
+                             kv_lengths=kv_lengths)[0]
+
+
+def rope_lanes(cfg: ModelConfig) -> int:
+    """Width of the stored rope key: ``rope_head_dim`` rounded up to the
+    TPU's 128 lanes, the rest zero.  A 64-wide minor dim takes 128 lanes
+    in HBM all the same; stated 64 wide, the decode loop's dot wants it
+    positions-minor and the whole stacked leaf is relaid out on the way
+    into the loop and back, every step."""
+    return -(-cfg.mla.rope_head_dim // 128) * 128
+
+
+def pad_rope(t, cfg: ModelConfig):
+    """Zero-pad the last dim of a rope key or query to ``rope_lanes``."""
+    extra = rope_lanes(cfg) - t.shape[-1]
+    return jnp.pad(t, [(0, 0)] * (t.ndim - 1) + [(0, extra)])
 
 
 def init_mla_cache(num_layers: int, batch: int, max_len: int,
                    cfg: ModelConfig, dtype=None):
+    """Stacked latent cache: ``ckv`` (L, B, Smax, kv_lora) and ``krope``
+    (L, B, Smax, rope_lanes), the rotated key zero-padded (``pad_rope``)."""
     m = cfg.mla
     dt = dtype or compute_dtype(cfg)
     return {
         "ckv": jnp.zeros((num_layers, batch, max_len, m.kv_lora_rank), dt),
-        "krope": jnp.zeros((num_layers, batch, max_len, m.rope_head_dim), dt),
+        "krope": jnp.zeros((num_layers, batch, max_len, rope_lanes(cfg)), dt),
         "length": jnp.zeros((batch,), jnp.int32),
     }
 
@@ -439,8 +533,8 @@ def init_mla_cache(num_layers: int, batch: int, max_len: int,
 def mla_decode_block(p, x1, cache, layer, lengths, cfg: ModelConfig):
     """Absorbed-matrix MLA decode: attention in the latent (kv_lora) space.
 
-    x1 (B,1,D); cache {"ckv": (L,B,Smax,kvr), "krope": (L,B,Smax,rope)},
-    written in place at ``[layer, b, lengths[b]]``.
+    x1 (B,1,D); cache {"ckv": (L,B,Smax,kvr), "krope": (L,B,Smax,lanes)}
+    (``init_mla_cache``), written in place at ``[layer, b, lengths[b]]``.
     Returns (out (B,1,D), cache)."""
     m = cfg.mla
     B = x1.shape[0]
@@ -449,36 +543,43 @@ def mla_decode_block(p, x1, cache, layer, lengths, cfg: ModelConfig):
     q_nope, q_rope = _mla_q(p, x1, cfg, positions)       # (B,1,H,n),(B,1,H,r)
     c_kv, k_rope = _mla_ckv(p, x1, cfg, positions)       # (B,1,kvr),(B,1,r)
     cache = stacked_write(cache, layer, positions,
-                          {"ckv": c_kv, "krope": k_rope})
-    c_cache, r_cache = cache["ckv"][layer], cache["krope"][layer]
+                          {"ckv": c_kv, "krope": pad_rope(k_rope, cfg)})
     # absorb W_UK into q: q_abs[b,h,c] = sum_n q_nope[b,h,n] * W_UK[c,h,n]
     kvb = p["kv_b"].reshape(m.kv_lora_rank, H, m.nope_head_dim + m.v_head_dim)
     w_uk = kvb[:, :, :m.nope_head_dim]                   # (kvr,H,n)
     w_uv = kvb[:, :, m.nope_head_dim:]                   # (kvr,H,v)
     q_abs = jnp.einsum("bhn,chn->bhc", q_nope[:, 0].astype(jnp.float32),
                        w_uk.astype(jnp.float32))         # (B,H,kvr)
-    scale = 1.0 / jnp.sqrt(m.nope_head_dim + m.rope_head_dim)
+    scale = mla_softmax_scale(cfg)
+    with jax.named_scope("mla.attend"):
+        out_lat = _mla_absorbed_attend(
+            q_abs, pad_rope(q_rope[:, 0], cfg), cache["ckv"][layer],
+            cache["krope"][layer], lengths + 1, scale)
+    out = jnp.einsum("bhc,chv->bhv", out_lat, w_uv.astype(jnp.float32))
+    out = out.reshape(B, 1, H * m.v_head_dim).astype(x1.dtype)
+    return out @ p["wo"], cache
+
+
+def _mla_absorbed_attend(q_abs, q_rope, c_cache, r_cache, valid_len, scale):
+    """One query per row against the latent cache, in the latent space:
+    q_abs (B,H,kvr), q_rope (B,H,lanes), c_cache (B,Smax,kvr), r_cache
+    (B,Smax,lanes); positions below ``valid_len`` (B,) attend.  Returns
+    the attended latent (B,H,kvr) float32."""
     if opt.enabled("attn_dtype"):
         scores = (jnp.einsum("bhc,btc->bht", q_abs.astype(c_cache.dtype),
                              c_cache, preferred_element_type=jnp.float32)
-                  + jnp.einsum("bhr,btr->bht", q_rope[:, 0], r_cache,
+                  + jnp.einsum("bhr,btr->bht", q_rope, r_cache,
                                preferred_element_type=jnp.float32)) * scale
     else:
         scores = (jnp.einsum("bhc,btc->bht", q_abs,
                              c_cache.astype(jnp.float32))
-                  + jnp.einsum("bhr,btr->bht",
-                               q_rope[:, 0].astype(jnp.float32),
+                  + jnp.einsum("bhr,btr->bht", q_rope.astype(jnp.float32),
                                r_cache.astype(jnp.float32))) * scale
     Smax = c_cache.shape[1]
-    valid = jnp.arange(Smax)[None, :] < (lengths + 1)[:, None]
+    valid = jnp.arange(Smax)[None, :] < valid_len[:, None]
     scores = jnp.where(valid[:, None, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     if opt.enabled("attn_dtype"):
-        out_lat = jnp.einsum("bht,btc->bhc", probs.astype(c_cache.dtype),
-                             c_cache, preferred_element_type=jnp.float32)
-    else:
-        out_lat = jnp.einsum("bht,btc->bhc", probs,
-                             c_cache.astype(jnp.float32))
-    out = jnp.einsum("bhc,chv->bhv", out_lat, w_uv.astype(jnp.float32))
-    out = out.reshape(B, 1, H * m.v_head_dim).astype(x1.dtype)
-    return out @ p["wo"], cache
+        return jnp.einsum("bht,btc->bhc", probs.astype(c_cache.dtype),
+                          c_cache, preferred_element_type=jnp.float32)
+    return jnp.einsum("bht,btc->bhc", probs, c_cache.astype(jnp.float32))

@@ -7,6 +7,7 @@ regardless of the compute dtype; matmuls run in the config dtype.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional
 
 import jax
@@ -98,12 +99,44 @@ def rope_freqs(head_dim: int, theta: float):
                             / head_dim))
 
 
-def apply_rope(x, positions, theta: float):
-    """x: (..., seq, heads, head_dim); positions: (..., seq) int32."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature term: 0.1 * mscale * ln(factor) + 1."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_freqs(dim: int, theta: float, ys) -> np.ndarray:
+    """YaRN rotary frequencies (``DeepseekV3YarnRotaryEmbedding``) for a
+    rotary dim ``dim`` under ``ys`` (a ``YarnConfig``): the extrapolated
+    theta^(-2i/dim) and the interpolated ones divided by ``factor``,
+    blended by a ramp over the dims between the correction dims of
+    beta_fast and beta_slow, d(r) = dim ln(L / (2 pi r)) / (2 ln theta)
+    with L the original context."""
+    pos = np.arange(0, dim, 2, dtype=np.float32) / dim
+    extra = 1.0 / (theta ** pos)
+    inter = 1.0 / (ys.factor * theta ** pos)
+
+    def corr(rot):
+        return (dim * math.log(ys.original_max_position / (rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr(ys.beta_fast)), 0)
+    high = min(math.ceil(corr(ys.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0.0, 1.0)
+    return (inter * ramp + extra * (1.0 - ramp)).astype(np.float32)
+
+
+def apply_rope(x, positions, theta: float, freqs=None):
+    """x: (..., seq, heads, head_dim); positions: (..., seq) int32.
+    ``freqs`` (head_dim/2,) replaces theta's plain frequencies (YaRN)."""
     if theta <= 0.0:
         return x
     hd = x.shape[-1]
-    freqs = jnp.asarray(rope_freqs(hd, theta))            # (hd/2,)
+    if freqs is None:
+        freqs = rope_freqs(hd, theta)
+    freqs = jnp.asarray(freqs)                            # (hd/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, hd/2)
     cos = jnp.cos(angles)[..., None, :]                   # (..., S, 1, hd/2)
     sin = jnp.sin(angles)[..., None, :]

@@ -19,27 +19,81 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import ModelConfig, MoEConfig
 from repro.models.layers import compute_dtype, dense_init, init_mlp, apply_mlp
 from repro.sharding import shard
 
 
+# the selection-only routing bias (deepseek-v3's e_score_correction_bias)
+# is drawn N(0, ROUTER_BIAS_STD^2) from the seed: random weights have no
+# trained bias, and this scale moves some top-k choices (the sigmoid scores
+# of a random router spread with a standard deviation near 0.2)
+ROUTER_BIAS_STD = 0.05
+
+
 def init_moe(key, cfg: ModelConfig):
-    """Params for ONE MoE layer (stack with stack_init for the layer scan)."""
+    """Params for ONE MoE layer (stack with stack_init for the layer scan).
+    The router spans every expert; the expert weights are those of the
+    experts held here, [0, moe.held)."""
     m = cfg.moe
     d, fe = cfg.d_model, m.d_ff_expert
+    n = m.held
     dt = compute_dtype(cfg)
     ks = jax.random.split(key, 5)
     p = {
         "router": dense_init(ks[0], (d, m.num_experts), jnp.float32),
-        "we_gate": dense_init(ks[1], (m.num_experts, d, fe), dt, in_axis=-2),
-        "we_up": dense_init(ks[2], (m.num_experts, d, fe), dt, in_axis=-2),
-        "we_down": dense_init(ks[3], (m.num_experts, fe, d), dt, in_axis=-2),
+        "we_gate": dense_init(ks[1], (n, d, fe), dt, in_axis=-2),
+        "we_up": dense_init(ks[2], (n, d, fe), dt, in_axis=-2),
+        "we_down": dense_init(ks[3], (n, fe, d), dt, in_axis=-2),
     }
+    if m.scoring == "sigmoid":
+        p["router_bias"] = ROUTER_BIAS_STD * jax.random.normal(
+            jax.random.fold_in(ks[0], 1), (m.num_experts,), jnp.float32)
     if m.num_shared_experts:
         shared = init_mlp(ks[4], cfg, d_ff=fe * m.num_shared_experts)
         p.update({"ws_" + k.split("_", 1)[1]: v for k, v in shared.items()})
     return p
+
+
+def route(p, x2, m: MoEConfig):
+    """Top-k routing of tokens x2 (T, D) over every expert, in float32.
+
+    softmax: probabilities over the experts, top-k of them.
+    sigmoid (deepseek-v3, ``noaux_tc``): scores sigmoid(x W_r); the choice
+    reads scores + ``router_bias``; each of ``n_group`` groups scores the
+    sum of its two best biased scores, the ``topk_group`` best groups are
+    kept and the top-k experts chosen among them; the weights are the
+    unbiased scores of the chosen experts.
+    Either way the weights are normalised to sum 1 (``norm_topk_prob``)
+    and multiplied by ``routed_scaling``.
+
+    Returns (weights (T,k), experts (T,k) int32, probs (T,E)): ``probs``
+    sum to 1 per token, for the load-balance loss."""
+    T = x2.shape[0]
+    k, E, G = m.top_k, m.num_experts, m.n_group
+    logits = x2.astype(jnp.float32) @ p["router"]          # (T,E)
+    if m.scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        biased = scores + p["router_bias"]
+        if G > 1:
+            grouped = biased.reshape(T, G, E // G)
+            best = jax.lax.top_k(grouped, min(2, E // G))[0].sum(-1)
+            _, keep = jax.lax.top_k(best, m.topk_group)       # (T,topk_group)
+            kept = jnp.zeros((T, G), bool).at[
+                jnp.arange(T)[:, None], keep].set(True)
+            biased = jnp.where(jnp.repeat(kept, E // G, axis=1), biased,
+                               -jnp.inf)
+        _, top_i = jax.lax.top_k(biased, k)
+        top_p = jnp.take_along_axis(scores, top_i, axis=-1)
+        probs = scores / scores.sum(-1, keepdims=True)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_p, top_i = jax.lax.top_k(probs, k)             # (T,k)
+    if m.norm_topk_prob:
+        top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    if m.routed_scaling != 1.0:
+        top_p = top_p * m.routed_scaling
+    return top_p, top_i, probs
 
 
 def _positions_in_expert(expert_ids: jnp.ndarray, num_experts: int):
@@ -96,11 +150,7 @@ def moe_block(p, x, cfg: ModelConfig, *, capacity_factor: float = 1.25
     k, E = m.top_k, m.num_experts
 
     # --- routing (fp32) ----------------------------------------------------
-    logits = x2.astype(jnp.float32) @ p["router"]          # (T,E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_i = jax.lax.top_k(probs, k)                 # (T,k)
-    if m.norm_topk_prob:
-        top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    top_p, top_i, probs = route(p, x2, m)                  # (T,k)
 
     # --- positions & capacity ----------------------------------------------
     flat_e = top_i.reshape(T * k)
@@ -139,12 +189,83 @@ def moe_block(p, x, cfg: ModelConfig, *, capacity_factor: float = 1.25
         y = y + apply_mlp(sp, x2, cfg)
 
     # --- load-balance aux loss (Switch) --------------------------------------
+    aux = _aux_loss(probs, top_i, E)
+
+    return y.reshape(orig_shape).astype(x.dtype), aux
+
+
+def _aux_loss(probs, top_i, E: int):
+    """Switch load-balance loss: E * sum(mean prob x dispatch fraction)."""
+    T, k = top_i.shape
     me = probs.mean(axis=0)                                 # (E,) mean prob
     one_hot = jax.nn.one_hot(top_i, E, dtype=jnp.float32)   # (T,k,E)
     ce = one_hot.sum(axis=(0, 1)) / (T * k)                 # dispatch fraction
-    aux = E * jnp.sum(me * ce)
+    return E * jnp.sum(me * ce)
 
-    return y.reshape(orig_shape).astype(x.dtype), aux
+
+# ---------------------------------------------------------------------------
+# Held-expert layer: one chip's share of an expert-parallel deployment
+# ---------------------------------------------------------------------------
+
+
+def moe_block_held(p, x, cfg: ModelConfig, *, first: int = 0, valid=None):
+    """The part of an MoE layer that the experts [first, first + held)
+    give, plus the shared experts: what one chip of an expert-parallel
+    deployment computes for its tokens, with no exchange.
+
+    Tokens route over every expert (``route``); for each token, the
+    chosen experts that are held here contribute w_e * down(silu(gate h)
+    * up h).  Dropless at every token count: each held expert runs over
+    every token (a buffer of T rows per held expert), with the weight of
+    a token that did not choose it zero, so the memory is T x held x
+    d_ff_expert and no capacity applies.
+
+    ``valid`` (x's leading shape, bool) marks the real tokens for the
+    counts.  Returns (y, aux, rows): rows (2,) int32 are the (token,
+    expert) rows of valid tokens that fell to the held experts, and all
+    that were routed."""
+    m = cfg.moe
+    orig_shape = x.shape
+    D = orig_shape[-1]
+    x2 = x.reshape(-1, D)
+    T = x2.shape[0]
+    n = m.held
+    with jax.named_scope("moe.route"):
+        top_p, top_i, probs = route(p, x2, m)
+        # (T, k, n): 1 where choice j of token t is held expert e
+        mine = jax.nn.one_hot(top_i - first, n, dtype=jnp.float32)
+        w = jnp.einsum("tkn,tk->nt", mine, top_p)           # (n, T)
+        ok = (jnp.ones((T,), bool) if valid is None
+              else valid.reshape(T))
+        held_rows = jnp.sum(mine.sum((1, 2)) * ok).astype(jnp.int32)
+        routed_rows = (jnp.sum(ok) * m.top_k).astype(jnp.int32)
+    with jax.named_scope("moe.experts"):
+        g = jnp.einsum("td,edf->etf", x2, p["we_gate"])
+        u = jnp.einsum("td,edf->etf", x2, p["we_up"])
+        h = jax.nn.silu(g) * u * w[:, :, None].astype(g.dtype)
+        y = jnp.einsum("etf,efd->td", h, p["we_down"])
+    if m.num_shared_experts:
+        sp = {"w_" + kk.split("_", 1)[1]: vv
+              for kk, vv in p.items() if kk.startswith("ws_")}
+        y = y + apply_mlp(sp, x2, cfg)
+    aux = _aux_loss(probs, top_i, m.num_experts)
+    rows = jnp.stack([held_rows, routed_rows])
+    return y.reshape(orig_shape).astype(x.dtype), aux, rows
+
+
+def moe_layer(p, x, cfg: ModelConfig, *, valid=None):
+    """The transformer's MoE layer: ``moe_block_held`` where the config
+    holds a share of the experts (``experts_held < num_experts``), else
+    ``moe_block`` over all of them.  Returns (y, aux, rows) as
+    ``moe_block_held``; with every expert held, held rows = routed rows."""
+    m = cfg.moe
+    if m.held < m.num_experts:
+        return moe_block_held(p, x, cfg, valid=valid)
+    y, aux = moe_block(p, x, cfg)
+    n_tok = (math.prod(x.shape[:-1]) if valid is None
+             else jnp.sum(valid))
+    routed = jnp.asarray(n_tok * m.top_k, jnp.int32)
+    return y, aux, jnp.stack([routed, routed])
 
 
 # ---------------------------------------------------------------------------
@@ -153,8 +274,9 @@ def moe_block(p, x, cfg: ModelConfig, *, capacity_factor: float = 1.25
 #
 # The capacity-buffer formulation above leaves the token->expert scatter to
 # the SPMD partitioner, which cannot partition an arbitrary scatter and
-# falls back to replicate + all-reduce of the (E, C, D) buffers — measured
-# at ~13 TB/device/step for deepseek-v3 train_4k (EXPERIMENTS.md §Perf).
+# falls back to replicate + all-reduce of the (E, C, D) buffers: ~13 TB a
+# device a step for deepseek-v3 train_4k by the dry run's cost analysis (not
+# a chip measurement; PERF.md holds those).
 #
 # moe_block_ep maps the communication pattern explicitly with shard_map:
 #
@@ -196,7 +318,10 @@ def moe_block_ep(p, x, cfg: ModelConfig, *, capacity_factor: float = 1.25
 
     dspec = data_ax if len(data_ax) > 1 else data_ax[0]
     x_spec = P(dspec, None)
-    router_spec = P(None, None)
+    # the router's params (weights and, for sigmoid scoring, the bias) are
+    # replicated: every shard routes its own tokens over every expert
+    rparams = {kk: p[kk] for kk in ("router", "router_bias") if kk in p}
+    router_spec = {kk: P(*(None,) * v.ndim) for kk, v in rparams.items()}
     wg_spec = P(dspec, None, model_ax)
     wd_spec = P(dspec, model_ax, None)
 
@@ -206,13 +331,9 @@ def moe_block_ep(p, x, cfg: ModelConfig, *, capacity_factor: float = 1.25
     shared_args = ((p["ws_gate"], p["ws_up"], p["ws_down"])
                    if has_shared else ())
 
-    def body(x_loc, router, wg, wu, wd, *shared):
+    def body(x_loc, rp, wg, wu, wd, *shared):
         # ---- local routing -------------------------------------------------
-        logits = x_loc.astype(jnp.float32) @ router            # (T_loc, E)
-        probs = jax.nn.softmax(logits, axis=-1)
-        top_p, top_i = jax.lax.top_k(probs, k)
-        if m.norm_topk_prob:
-            top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+        top_p, top_i, probs = route(rp, x_loc, m)
         flat_e = top_i.reshape(T_loc * k)
         pos = _positions_in_expert(flat_e, E)
         keep = pos < C_src
@@ -282,6 +403,6 @@ def moe_block_ep(p, x, cfg: ModelConfig, *, capacity_factor: float = 1.25
         + shared_specs,
         out_specs=(x_spec, P()),
         check_vma=False)
-    y, aux = fn(x2, p["router"], p["we_gate"], p["we_up"], p["we_down"],
+    y, aux = fn(x2, rparams, p["we_gate"], p["we_up"], p["we_down"],
                 *shared_args)
     return y.reshape(orig_shape).astype(x.dtype), aux

@@ -21,7 +21,7 @@ from repro.models import attention as attn
 from repro.models.layers import (
     apply_mlp, apply_norm, compute_dtype, cross_entropy_loss, dense_init,
     embed_init, init_mlp, init_norm, stack_init)
-from repro.models.moe import init_moe, moe_block
+from repro.models.moe import init_moe, moe_layer
 from repro.sharding import shard
 
 
@@ -93,23 +93,29 @@ def _attn_full(lp, h, cfg, positions, kv_lengths, window):
                                 kv_lengths=kv_lengths)
 
 
+def _block_tail(cfg: ModelConfig, moe: bool, lp, x, h, attn_out,
+                valid=None):
+    """The residual adds and the feed-forward of one block, given its
+    normed input ``h`` and attention output.  Returns (x, aux_loss, rows):
+    rows (2,) int32 are an expert layer's (held, routed) rows of the
+    ``valid`` tokens (``moe_layer``), None for a dense layer."""
+    aux = jnp.zeros((), jnp.float32)
+    if cfg.parallel_block:
+        return x + attn_out + apply_mlp(lp["mlp"], h, cfg), aux, None
+    x = x + attn_out
+    h2 = apply_norm(lp["ln2"], x, cfg)
+    if moe:
+        mo, aux, rows = moe_layer(lp["moe"], h2, cfg, valid=valid)
+        return x + mo, aux, rows
+    return x + apply_mlp(lp["mlp"], h2, cfg), aux, None
+
+
 def _layer_full(cfg: ModelConfig, moe: bool, window, x, lp, positions,
                 kv_lengths):
     """One block, full sequence. Returns (x, aux_loss)."""
     h = apply_norm(lp["ln1"], x, cfg)
     attn_out = _attn_full(lp, h, cfg, positions, kv_lengths, window)
-    aux = jnp.zeros((), jnp.float32)
-    if cfg.parallel_block:
-        mlp_out = apply_mlp(lp["mlp"], h, cfg)
-        x = x + attn_out + mlp_out
-    else:
-        x = x + attn_out
-        h2 = apply_norm(lp["ln2"], x, cfg)
-        if moe:
-            mo, aux = moe_block(lp["moe"], h2, cfg)
-            x = x + mo
-        else:
-            x = x + apply_mlp(lp["mlp"], h2, cfg)
+    x, aux, _ = _block_tail(cfg, moe, lp, x, h, attn_out)
     # under seq_parallel the carried residual (and thus every remat-saved
     # activation) is sharded over `model` along seq (Megatron-SP)
     x = shard(x, "batch", "seq_sp", None)
@@ -230,6 +236,10 @@ def init_state(cfg: ModelConfig, batch: int, max_len: int,
     mk_cache = (attn.init_mla_cache if cfg.attn_kind == "mla"
                 else attn.init_kv_cache)
     state: Dict[str, Any] = {}
+    if cfg.moe is not None:
+        # the last step's (held, routed) expert rows (``moe_layer``): read
+        # by the host with the step's tokens, never fed back
+        state["moe_rows"] = jnp.zeros((2,), jnp.int32)
     if n_dense:
         c = mk_cache(n_dense, batch, max_len, cfg, dtype)
         c.pop("length")
@@ -243,6 +253,8 @@ def init_state(cfg: ModelConfig, batch: int, max_len: int,
 
 def _layer_decode(cfg: ModelConfig, moe: bool, window, x, lp, cache, layer,
                   lengths):
+    """One block of the decode step.  Returns (x, cache, rows): rows are
+    an expert layer's (held, routed) expert rows, None for a dense one."""
     h = apply_norm(lp["ln1"], x, cfg)
     if cfg.attn_kind == "mla":
         attn_out, cache = attn.mla_decode_block(lp["attn"], h, cache, layer,
@@ -250,35 +262,28 @@ def _layer_decode(cfg: ModelConfig, moe: bool, window, x, lp, cache, layer,
     else:
         attn_out, cache = attn.stacked_decode_attn_block(
             lp["attn"], h, cache, layer, lengths, cfg, window=window)
-    if cfg.parallel_block:
-        x = x + attn_out + apply_mlp(lp["mlp"], h, cfg)
-    else:
-        x = x + attn_out
-        h2 = apply_norm(lp["ln2"], x, cfg)
-        if moe:
-            mo, _ = moe_block(lp["moe"], h2, cfg)
-            x = x + mo
-        else:
-            x = x + apply_mlp(lp["mlp"], h2, cfg)
-    return x, cache
+    x, _, rows = _block_tail(cfg, moe, lp, x, h, attn_out)
+    return x, cache, rows
 
 
 def _scan_carried(layer_fn, stacked, cache, x):
-    """Scan ``layer_fn(x, lp, cache, layer) -> (x, cache)`` over the stacked
-    params with the whole stacked cache in the CARRY: each layer writes its
-    new tokens at ``[layer, ...]`` of the loop's one buffer, so a donated
-    cache is updated in place, with no per-layer slice restacked into a
-    scan output and no whole-cache copy after the loop."""
+    """Scan ``layer_fn(x, lp, cache, layer) -> (x, cache, ys)`` over the
+    stacked params with the whole stacked cache in the CARRY: each layer
+    writes its new tokens at ``[layer, ...]`` of the loop's one buffer, so
+    a donated cache is updated in place, with no per-layer slice
+    restacked into a scan output and no whole-cache copy after the loop.
+    Returns (x, cache, ys stacked over the layers)."""
     num_layers = jax.tree_util.tree_leaves(stacked)[0].shape[0]
 
     def step(carry, xs):
         x, cache = carry
         lp, layer = xs
-        return layer_fn(x, lp, cache, layer), None
+        x, cache, ys = layer_fn(x, lp, cache, layer)
+        return (x, cache), ys
 
-    (x, cache), _ = jax.lax.scan(step, (x, cache),
-                                 (stacked, jnp.arange(num_layers)))
-    return x, cache
+    (x, cache), ys = jax.lax.scan(step, (x, cache),
+                                  (stacked, jnp.arange(num_layers)))
+    return x, cache, ys
 
 
 def _scan_decode(cfg, stacked, cache, x, lengths, *, moe: bool, window):
@@ -296,11 +301,15 @@ def decode_step(params, token, state, cfg: ModelConfig, *,
     x = shard(x, "batch", None, None)
     new_state = dict(state)
     if "cache_dense" in state:
-        x, nc = _scan_decode(cfg, params["dense_layers"], state["cache_dense"],
-                             x, lengths, moe=False, window=window)
+        x, nc, _ = _scan_decode(cfg, params["dense_layers"],
+                                state["cache_dense"], x, lengths, moe=False,
+                                window=window)
         new_state["cache_dense"] = nc
-    x, nc = _scan_decode(cfg, params["layers"], state["cache"], x, lengths,
-                         moe=cfg.moe is not None, window=window)
+    x, nc, rows = _scan_decode(cfg, params["layers"], state["cache"], x,
+                               lengths, moe=cfg.moe is not None,
+                               window=window)
+    if cfg.moe is not None:
+        new_state["moe_rows"] = rows.sum(0)
     new_state["cache"] = nc
     h = apply_norm(params["final_norm"], x, cfg)
     logits = project_logits(params, h, cfg)[:, 0]
@@ -338,23 +347,14 @@ def _layer_verify(cfg: ModelConfig, moe: bool, window, x, lp, cache, layer,
     out = jnp.stack(outs, axis=1).reshape(B, W,
                                           cfg.num_heads * cfg.head_dim)
     attn_out = out @ lp["attn"]["wo"] + lp["attn"].get("bo", 0.0)
-    if cfg.parallel_block:
-        x = x + attn_out + apply_mlp(lp["mlp"], h, cfg)
-    else:
-        x = x + attn_out
-        h2 = apply_norm(lp["ln2"], x, cfg)
-        if moe:
-            mo, _ = moe_block(lp["moe"], h2, cfg)
-            x = x + mo
-        else:
-            x = x + apply_mlp(lp["mlp"], h2, cfg)
-    return x, cache
+    x, _, _ = _block_tail(cfg, moe, lp, x, h, attn_out)
+    return x, cache, None
 
 
 def _scan_verify(cfg, stacked, cache, x, lengths, *, moe: bool, window):
     layer_fn = functools.partial(_layer_verify, cfg, moe, window,
                                  lengths=lengths)
-    return _scan_carried(layer_fn, stacked, cache, x)
+    return _scan_carried(layer_fn, stacked, cache, x)[:2]
 
 
 def verify_decode_step(params, tokens, state, cfg: ModelConfig, *,
@@ -407,20 +407,22 @@ def prefill(params, tokens, state, cfg: ModelConfig, *, lengths=None,
     positions = jnp.arange(S)[None, :]
     new_state = dict(state)
 
+    # real (unpadded) tokens, for the expert layers' row counts
+    valid = positions < lengths[:, None] if cfg.moe is not None else None
+
     def run_stack(x, stacked, cache, moe):
         def step(x, xs):
             lp, cache_layer = xs
             h = apply_norm(lp["ln1"], x, cfg)
             if cfg.attn_kind == "mla":
-                attn_out = attn.mla_attention_block(
+                attn_out, c_kv, k_rope = attn.mla_prefill_block(
                     lp["attn"], h, cfg, positions=positions,
                     kv_lengths=lengths)
-                c_kv, k_rope = attn._mla_ckv(lp["attn"], h, cfg, positions)
                 Smax = cache_layer["ckv"].shape[1]
                 pad = [(0, 0), (0, Smax - S), (0, 0)]
-                new_cache = {
+                new_cache = {   # as init_mla_cache
                     "ckv": jnp.pad(c_kv, pad).astype(cache_layer["ckv"].dtype),
-                    "krope": jnp.pad(k_rope, pad).astype(
+                    "krope": jnp.pad(attn.pad_rope(k_rope, cfg), pad).astype(
                         cache_layer["krope"].dtype),
                 }
             else:
@@ -444,28 +446,23 @@ def prefill(params, tokens, state, cfg: ModelConfig, *, lengths=None,
 
                 new_cache = {"k": fill(k, cache_layer["k"]),
                              "v": fill(v, cache_layer["v"])}
-            if cfg.parallel_block:
-                x2 = x + attn_out + apply_mlp(lp["mlp"], h, cfg)
-            else:
-                x2 = x + attn_out
-                h2 = apply_norm(lp["ln2"], x2, cfg)
-                if moe:
-                    mo, _ = moe_block(lp["moe"], h2, cfg)
-                    x2 = x2 + mo
-                else:
-                    x2 = x2 + apply_mlp(lp["mlp"], h2, cfg)
+            x2, _, rows = _block_tail(cfg, moe, lp, x, h, attn_out,
+                                      valid=valid)
             x2 = shard(x2, "batch", None, None)
-            return x2, new_cache
+            return x2, (new_cache, rows)
 
-        return jax.lax.scan(step, x, (stacked, cache))
+        x, (cache, rows) = jax.lax.scan(step, x, (stacked, cache))
+        return x, cache, (None if rows is None else rows.sum(0))
 
     if "cache_dense" in state:
-        x, nc = run_stack(x, params["dense_layers"], state["cache_dense"],
-                          False)
+        x, nc, _ = run_stack(x, params["dense_layers"], state["cache_dense"],
+                             False)
         new_state["cache_dense"] = nc
-    x, nc = run_stack(x, params["layers"], state["cache"],
-                      cfg.moe is not None)
+    x, nc, rows = run_stack(x, params["layers"], state["cache"],
+                            cfg.moe is not None)
     new_state["cache"] = nc
+    if cfg.moe is not None:
+        new_state["moe_rows"] = rows
     h = apply_norm(params["final_norm"], x, cfg)
     # logits at each row's last valid position
     rows = jnp.arange(B)

@@ -787,6 +787,9 @@ class ReplicaPool:
             bd, ed = base.get("decode", {}), extra.get("decode", {})
             for k in self._DECODE_SUM_KEYS:
                 bd[k] = bd.get(k, 0) + ed.get(k, 0)
+            for k in ("moe_held_rows_total", "moe_routed_rows_total"):
+                if k in ed:                  # expert models only
+                    bd[k] = bd.get(k, 0) + ed[k]
             for k in ("phase_ms_total", "phase_count"):
                 for p, v in ed.get(k, {}).items():
                     bd[k][p] = bd[k].get(p, 0) + v

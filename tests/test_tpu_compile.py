@@ -161,3 +161,56 @@ def test_decode_step_updates_cache_in_place(one_chip, arch, num_layers,
         on_chip = "S(" in m.group(2)
         assert n != whole, m.group(0)
         assert on_chip or n != layer, m.group(0)
+
+
+# deepseek-v3-671b at the benchmark's cut (bench/configs/
+# deepseek-v3-7L-ep32.json): 7 layers, 8 of 256 experts held, no MTP,
+# 64 decode slots of 2048 positions, prefill groups of up to 2 prompts
+DSV3_SLOTS, DSV3_GROUP, DSV3_LEN = 64, 2, 2048
+
+
+def _dsv3_cut():
+    base = get_config("deepseek-v3-671b")
+    return dataclasses.replace(
+        base, num_layers=7, mtp=False,
+        moe=dataclasses.replace(base.moe, experts_held=8))
+
+
+def test_deepseek_v3_cut_fits_and_updates_latent_cache_in_place(one_chip):
+    """The served decode step of the cut writes each token into the
+    donated latent cache (``ckv``, ``krope``) in place, with no copy of a
+    whole stacked leaf or of a layer's slice in HBM; and the weights, the
+    pooled cache and a 2 x 2048-token prefill's temporaries fit one
+    chip's HBM together."""
+    cfg = _dsv3_cut()
+    compiled, state = _compile_decode_step(one_chip, cfg, DSV3_SLOTS,
+                                           DSV3_LEN)
+    dec = compiled.memory_analysis()
+    text = compiled.as_text()
+    for stack in ("cache", "cache_dense"):
+        for leaf in ("ckv", "krope"):
+            shape = state[stack][leaf].shape
+            assert shape[1:3] == (DSV3_SLOTS, DSV3_LEN)
+            whole, layer = math.prod(shape), math.prod(shape[1:])
+            for m in _COPY.finditer(text):
+                n = math.prod(int(d) for d in m.group(1).split(",") if d)
+                assert n != whole, m.group(0)
+                assert "S(" in m.group(2) or n != layer, m.group(0)
+    model = build_model(cfg)
+    place = lambda t: jax.tree_util.tree_map(           # noqa: E731
+        lambda x: _sds(x.shape, x.dtype, one_chip), t)
+    params = place(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    engine = InferenceEngine(model, params, max_len=DSV3_LEN,
+                             max_batch=DSV3_GROUP)
+    pre = engine._prefill.lower(
+        params, {"tokens": _sds((DSV3_GROUP, DSV3_LEN), jnp.int32, one_chip),
+                 "lengths": _sds((DSV3_GROUP,), jnp.int32, one_chip)},
+        place(model.state_specs(DSV3_GROUP, DSV3_LEN))).compile()
+    pm = pre.memory_analysis()
+    assert dec.argument_size_in_bytes > 12 * 10 ** 9    # weights + cache
+    # resident weights and pooled cache, the prefill's temporaries and its
+    # group cache, as the scheduler holds them while it admits
+    peak = (dec.argument_size_in_bytes + pm.temp_size_in_bytes
+            + pm.output_size_in_bytes)
+    assert peak < HBM_BYTES, f"{peak / 2 ** 30:.2f} GiB"
+    assert dec.temp_size_in_bytes < 2 ** 30, dec.temp_size_in_bytes
